@@ -5,6 +5,9 @@ Prints ``name,us_per_call,derived`` CSV rows (one per measured quantity).
   PYTHONPATH=src python -m benchmarks.run            # all
   PYTHONPATH=src python -m benchmarks.run fig13      # one suite
   PYTHONPATH=src python -m benchmarks.run --smoke    # fast CI subset
+
+A suite that raises prints a ``<suite>_FAILED`` row and the run goes on to
+the next suite; the process then exits 1.
 """
 from __future__ import annotations
 
@@ -54,7 +57,10 @@ def main() -> None:
         os.environ["REPRO_BENCH_SMOKE"] = "1"
         if which is None:        # bare --smoke: the fast CI subset
             suites = [(t, m) for t, m in SUITES if t in SMOKE_SUITES]
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
+    failed = []
     for tag, modname in suites:
         if which and which != tag:
             continue
@@ -65,6 +71,10 @@ def main() -> None:
         except Exception as e:
             traceback.print_exc()
             print(f"{tag}_FAILED,0,{type(e).__name__}", flush=True)
+            failed.append(tag)
+    if failed:
+        print(f"failed suites: {' '.join(failed)}", file=sys.stderr)
+        sys.exit(1)
 
 
 if __name__ == "__main__":

@@ -3,7 +3,7 @@
 The pass replays a captured production call of ``vdes.simulate`` /
 ``vdes.simulate_ensemble`` under ``jax.make_jaxpr`` (static argnames closed
 over, array arguments traced) and walks the jaxpr recursively — through
-nested ``pjit`` bodies and into ``while``/``scan`` subjaxprs — checking:
+nested ``jit`` bodies and into ``while``/``scan`` subjaxprs — checking:
 
 - **while-fma** — an f32 multiply whose (sole) consumer is an add/sub
   inside the wave-loop body: exactly the shape XLA contracts into an FMA
@@ -170,8 +170,8 @@ class _JaxprAuditor:
 
     def _producer_through_transparent(self, producers: Dict, var):
         """The eqn producing ``var``, looking through shape plumbing and
-        into nested ``pjit`` bodies (``jnp.where``/``jnp.maximum`` wrap
-        their select/max in a pjit on this JAX version, so the guard lives
+        into nested ``jit`` bodies (``jnp.where``/``jnp.maximum`` wrap
+        their select/max in a ``jit`` eqn, so the guard lives
         one scope down)."""
         for _ in range(16):
             eqn = producers.get(id(var))
@@ -180,7 +180,7 @@ class _JaxprAuditor:
             if eqn.primitive.name in TRANSPARENT_PRIMS:
                 var = eqn.invars[0]
                 continue
-            if eqn.primitive.name == "pjit":
+            if eqn.primitive.name == "jit":
                 inner = eqn.params["jaxpr"].jaxpr
                 try:
                     idx = [id(v) for v in eqn.outvars].index(id(var))
@@ -336,7 +336,7 @@ def run_jaxpr_audit(root: str) -> List[Finding]:
         # (invisible) under the production x64-off config — give it its
         # declared width and re-check the carry
         import jax
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             closed64 = trace_call(calls[0], "simulate")
         findings += audit_carry_only(closed64, root, "vdes.simulate[x64]")
 

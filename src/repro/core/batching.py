@@ -1,4 +1,4 @@
-"""Padding/stacking for batched SPMD simulation (the jit+vmap lowering).
+"""Padding/stacking for batched simulation (the jit+vmap lowering).
 
 A grid of experiment points (capacities x interarrival factors x policies x
 operational scenarios, times Monte-Carlo replicas) is heterogeneous: each

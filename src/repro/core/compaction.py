@@ -195,7 +195,7 @@ def simulate_ensemble_compacted(
     log = log if log is not None else CompactionLog()
     statics = {k: kw.pop(k, None) for k in STATIC_KEYS}
     if statics["admission_sort"] is None:
-        statics["admission_sort"] = "fused"
+        statics["admission_sort"] = "select"
     inputs = dict(arrival=arrival, n_tasks=n_tasks, task_res=task_res,
                   service=service, priority=priority, capacities=capacities)
     inputs.update({k: v for k, v in kw.items() if v is not None})

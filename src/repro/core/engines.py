@@ -11,7 +11,7 @@ engine and call it. Three implementations ship:
     ``jit``+``vmap`` call of ``vdes.simulate_ensemble``: every grid point
     (its capacities, its admission policy, its compiled operational
     scenario) becomes a row of the batch, so a 24-point capacity x load x
-    scenario grid costs one XLA compile and one SPMD execution.
+    scenario grid costs one XLA compile and one device execution.
   - :class:`JaxCompactEngine` (``"jax-compact"``) — the batched engine with
     :mod:`repro.core.compaction`: the wave loop runs in segments, finished
     replicas and DONE pipelines drop out of the working set between
@@ -265,7 +265,7 @@ def _single_result(spec, wl, compiled, tr, wall, rel=None):
     summary["pipelines_per_s"] = summary["n_pipelines"] / max(wall, 1e-9)
     return ExperimentResult(spec, summary, rec, wall,
                             lifecycle=lifecycle_result(tr),
-                            timeline=_probe_timeline(spec, tr))
+                            timeline=_probe_timeline(spec, tr), trace=tr)
 
 
 def _probe_timeline(spec, tr):
@@ -348,15 +348,22 @@ class NumpyEngine:
 # ---------------------------------------------------------------------------
 
 class JaxEngine:
-    """Vectorized engine; ensembles and sweep grids are one SPMD batch."""
+    """Vectorized engine; ensembles and sweep grids are one batched call.
+    ``admission_sort`` picks the admission ranking (``"select"`` lex-min
+    rounds, ``"fused"`` lax.sort, ``"pallas"`` kernel, ...; see
+    :func:`repro.core.vdes.simulate`) — all variants are bit-identical."""
 
     name = "jax"
+
+    def __init__(self, admission_sort: str = "select"):
+        self.admission_sort = admission_sort
 
     def _ensemble(self, *args, **kwargs):
         """The one batched simulate call (overridden by
         :class:`JaxCompactEngine` to substitute the segmented compaction
         driver). Everything above this seam — padding, stacking, result
         slicing — is shared between the two engines."""
+        kwargs.setdefault("admission_sort", self.admission_sort)
         return vdes.simulate_ensemble(*args, **kwargs)
 
     def run(self, spec, params=None):
@@ -371,7 +378,8 @@ class JaxEngine:
                                         if fleets is not None else None,
                                         probe=probe,
                                         reliability=rels[0]
-                                        if rels is not None else None)
+                                        if rels is not None else None,
+                                        admission_sort=self.admission_sort)
             return _single_result(spec, wls[0], comp, tr,
                                   time.perf_counter() - t0,
                                   rel=rels[0] if rels is not None else None)
@@ -499,7 +507,7 @@ class JaxEngine:
                 results.append(ExperimentResult(
                     spec, summary, recs[0], wall,
                     lifecycle=lifecycle_result(last_tr),
-                    timeline=_probe_timeline(spec, last_tr)))
+                    timeline=_probe_timeline(spec, last_tr), trace=last_tr))
             else:
                 results.append(_aggregate_replicas(spec, sums, recs, wall))
         return results
@@ -580,7 +588,7 @@ class JaxStreamEngine:
     name = "jax-stream"
 
     def __init__(self, window_s=None, overlap: bool = True,
-                 min_rows: int = 64, admission_sort: str = "fused"):
+                 min_rows: int = 64, admission_sort: str = "select"):
         self.window_s = window_s
         self.overlap = overlap
         self.min_rows = min_rows

@@ -176,6 +176,10 @@ class ExperimentResult:
     # channel timelines at the probe's tick grid) — set for single-replica
     # runs of specs with a ProbeSpec
     timeline: Optional[object] = None
+    # the engine's raw SimTrace (every engine-recorded buffer and the wave
+    # count) — set for single-replica runs of the numpy and batched JAX
+    # engines; what engine-parity checks compare
+    trace: Optional[M.SimTrace] = None
 
     def save(self, directory: str) -> None:
         os.makedirs(directory, exist_ok=True)

@@ -26,31 +26,6 @@ from __future__ import annotations
 import numpy as np
 
 
-_BARRIER_BATCHABLE = False
-
-
-def _ensure_barrier_batchable():
-    """Register the (trivial, identity) vmap batching rule for
-    ``optimization_barrier`` on JAX versions that ship without one — newer
-    JAX has it upstream; on 0.4.x a vmapped barrier raises
-    ``NotImplementedError`` otherwise. The barrier is element-agnostic, so
-    binding directly on the batched operands with unchanged batch dims is
-    exact."""
-    global _BARRIER_BATCHABLE
-    if _BARRIER_BATCHABLE:
-        return
-    from jax._src.lax import lax as _lax_internal
-    from jax.interpreters import batching
-
-    prim = getattr(_lax_internal, "optimization_barrier_p", None)
-    if prim is not None and prim not in batching.primitive_batchers:
-        def _rule(args, dims):
-            return prim.bind(*args), dims
-
-        batching.primitive_batchers[prim] = _rule
-    _BARRIER_BATCHABLE = True
-
-
 def rounded_product(b, c, xp=np):
     """``b * c`` rounded to the storage dtype before any downstream use.
 
@@ -64,7 +39,6 @@ def rounded_product(b, c, xp=np):
         return prod
     import jax
 
-    _ensure_barrier_batchable()
     return jax.lax.optimization_barrier(prod)
 
 

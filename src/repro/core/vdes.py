@@ -74,10 +74,10 @@ workloads, including under operational scenarios:
     crashes part-way through).
 
 Because the function stays pure jnp, it can be ``jax.vmap``-ed over a replica
-axis and ``jax.jit``-ed / sharded — the TPU-native payoff: Monte-Carlo
-ensembles of *operational scenarios* (per-replica capacity schedules,
-controller gains, failure draws, and backoff constants) run as one SPMD
-program (see ``benchmarks/controller_bench.py`` and
+axis and ``jax.jit``-ed: Monte-Carlo ensembles of *operational scenarios*
+(per-replica capacity schedules, controller gains, failure draws, and
+backoff constants) run as one batched program on one device (see
+``benchmarks/controller_bench.py`` and
 ``examples/autoscaling_scenarios.py``).
 
 Time is float32; recommended horizons <= ~30 days keep the clock ulp below
@@ -267,6 +267,51 @@ def admission_mask_dense(res_q: jnp.ndarray, pkey: jnp.ndarray,
     return (res_q < nres) & (seat < free_q)
 
 
+def admission_mask_select(res_q: jnp.ndarray, pkey: jnp.ndarray,
+                          enq_wave: jnp.ndarray, free: jnp.ndarray, *,
+                          skip_pkey: bool = False) -> jnp.ndarray:
+    """Sort-free admission by repeated lexicographic minimum: the ``[N]``
+    bool admitted mask, directly.
+
+    Resource ``r`` admits the ``min(free[r], queued on r)`` jobs with the
+    smallest ``(pkey, enq_wave, id)`` keys — the same set as the sorted
+    seat test of :func:`admission_order` (the id makes every key unique).
+    Each round of the inner ``lax.while_loop`` picks, for every resource
+    still owed a job, its lex-minimum not-yet-admitted job with three
+    masked minimum reductions (pkey, then enqueue wave, then id). Only
+    exact compares and min reductions, no arithmetic: the mask is
+    bit-identical to the other rankings. The work is O(N) per admitted
+    job instead of a sort of all N rows per wave — a wave typically
+    admits the one or two jobs whose slots just freed — so this is the
+    ranking for wide batches on the TPU, where a ``[R, N]`` multi-key sort
+    and the scatter that undoes it cost milliseconds per wave.
+
+    ``skip_pkey`` (static) drops the pkey round; valid only when every
+    pkey is identical (static FIFO), see :func:`admission_mask_dense`."""
+    n = res_q.shape[0]
+    nres = free.shape[0]
+    ids = jnp.arange(n, dtype=jnp.int32)
+    of_r = res_q[None, :] == jnp.arange(nres, dtype=jnp.int32)[:, None]
+    owed = jnp.minimum(free, jnp.sum(of_r, axis=1, dtype=jnp.int32))
+    top = jnp.iinfo(jnp.int32).max
+
+    def lex_min(cand, key, fill):
+        k = jnp.where(cand, key[None, :], fill)
+        return cand & (k == jnp.min(k, axis=1, keepdims=True))
+
+    def pick(carry):
+        k, admitted = carry
+        cand = of_r & ~admitted[None, :] & (k < owed)[:, None]
+        if not skip_pkey:
+            cand = lex_min(cand, pkey, INF)
+        cand = lex_min(lex_min(cand, enq_wave, top), ids, top)
+        return k + 1, admitted | jnp.any(cand, axis=0)
+
+    _, admitted = jax.lax.while_loop(lambda c: c[0] < jnp.max(owed), pick,
+                                     (jnp.int32(0), jnp.zeros((n,), bool)))
+    return admitted
+
+
 @partial(jax.jit,
          static_argnames=("policy", "n_attempt_slots", "admission_sort",
                           "n_ctrl_slots", "n_probe_slots", "n_rel_slots",
@@ -280,7 +325,7 @@ def simulate(vwl: VWorkload, capacities: jnp.ndarray, policy: int = POLICY_FIFO,
              n_attempt_slots: Optional[int] = None,
              controller: Optional[jnp.ndarray] = None,
              fail_holds_frac=None,
-             admission_sort: str = "fused",
+             admission_sort: str = "select",
              n_ctrl_slots: Optional[int] = None,
              fleet=None, trig=None, obs_noise=None, drift_inc=None,
              pool_gain=None, pool_base=None, n_pool_eff=None,
@@ -311,8 +356,10 @@ def simulate(vwl: VWorkload, capacities: jnp.ndarray, policy: int = POLICY_FIFO,
     queue-reactive scaling inside the loop. ``fail_holds_frac`` (traced
     scalar, default None = 1.0) makes a *failing* attempt hold its slot for
     only that fraction of its service time. ``admission_sort`` selects the
-    fused ``lax.sort`` ranking (default) or the ``"chained"`` 3-argsort
-    reference.
+    admission ranking (static; every choice gives the same admitted mask):
+    ``"select"`` lex-min rounds (default, :func:`admission_mask_select`),
+    ``"fused"`` 3-key ``lax.sort``, ``"chained"`` 3-argsort reference,
+    ``"dense"`` pairwise seat count, ``"pallas"`` kernel.
 
     ``n_ctrl_slots = E`` (static; use :func:`repro.core.des.ctrl_tick_bound`
     — actions only happen at evaluation ticks, so the compile-time tick grid
@@ -381,7 +428,8 @@ def simulate(vwl: VWorkload, capacities: jnp.ndarray, policy: int = POLICY_FIFO,
     n, T = vwl.task_res.shape
     if (cap_times is None) != (cap_vals is None):
         raise ValueError("cap_times and cap_vals must be given together")
-    if admission_sort not in ("fused", "chained", "dense", "pallas"):
+    if admission_sort not in ("fused", "chained", "dense", "select",
+                              "pallas"):
         raise ValueError(f"unknown admission_sort {admission_sort!r}")
     rank = (admission_order if admission_sort == "fused"
             else admission_order_chained)
@@ -680,11 +728,13 @@ def simulate(vwl: VWorkload, capacities: jnp.ndarray, policy: int = POLICY_FIFO,
 
     def _admission_stage(s, t_star):
         """Stage 4: one ranked admission round per resource, recording
-        start/finish for admitted attempts. Four equivalent rankings select
+        start/finish for admitted attempts. Five equivalent rankings select
         the same admitted mask (bit-identical, see
-        :func:`admission_mask_dense`): ``"fused"`` — one stable 3-key
-        ``lax.sort``; ``"chained"`` — three stable argsorts; ``"dense"`` —
-        sort-free pairwise seat count (the fast CPU path); ``"pallas"`` —
+        :func:`admission_mask_dense`): ``"select"`` — repeated lex-min
+        picks, O(N) per admitted job (the default); ``"fused"`` — one
+        stable 3-key ``lax.sort``; ``"chained"`` — three stable argsorts;
+        ``"dense"`` — sort-free pairwise seat count (the fast path at
+        compacted widths on CPU); ``"pallas"`` —
         the fused VMEM kernel in :mod:`repro.kernels.queue_scan`
         (interpreted off-TPU)."""
         s = dict(s)
@@ -726,13 +776,14 @@ def simulate(vwl: VWorkload, capacities: jnp.ndarray, policy: int = POLICY_FIFO,
                                         jnp.zeros((1,), jnp.int32)])
             admit_sorted = seat < free_ext[r_s]
             admitted = jnp.zeros((n,), bool).at[o].set(admit_sorted) & queued
-        elif admission_sort == "dense":
+        elif admission_sort in ("dense", "select"):
             # statically-FIFO runs have pkey == 0 everywhere: skip the f32
-            # pkey compares in the pairwise matrix (bit-identical mask)
+            # pkey compares (bit-identical mask)
             fifo_static = policy_dyn is None and policy == POLICY_FIFO
-            admitted = admission_mask_dense(res_q, pkey, s["enq_wave"],
-                                            s["free"],
-                                            skip_pkey=fifo_static) & queued
+            mask = (admission_mask_dense if admission_sort == "dense"
+                    else admission_mask_select)
+            admitted = mask(res_q, pkey, s["enq_wave"], s["free"],
+                            skip_pkey=fifo_static) & queued
         else:  # "pallas": fused admission kernel (interpreted off-TPU)
             from repro.kernels.queue_scan import fused_admission
             admitted = fused_admission(res_q, pkey, s["enq_wave"],
@@ -1035,7 +1086,8 @@ def simulate(vwl: VWorkload, capacities: jnp.ndarray, policy: int = POLICY_FIFO,
 
 def simulate_to_trace(wl: M.Workload, platform: Optional[M.PlatformConfig] = None,
                       policy: int = POLICY_FIFO, scenario=None,
-                      fleet=None, probe=None, reliability=None) -> M.SimTrace:
+                      fleet=None, probe=None, reliability=None,
+                      admission_sort: str = "select") -> M.SimTrace:
     """Convenience: numpy Workload in, SimTrace out (single replica).
     ``scenario`` is a :class:`repro.ops.scenario.CompiledScenario`;
     ``fleet`` a :class:`repro.ops.scenario.CompiledFleet` (``wl`` must then
@@ -1044,7 +1096,8 @@ def simulate_to_trace(wl: M.Workload, platform: Optional[M.PlatformConfig] = Non
     sampling onto the trace's ``probe_times``/``probe_vals``);
     ``reliability`` a :class:`repro.reliability.compile.CompiledReliability`
     (correlated outage/repair/eviction capacity events recorded onto the
-    trace's ``rel_times``/``rel_caps``)."""
+    trace's ``rel_times``/``rel_caps``). ``admission_sort`` selects the
+    admission ranking as in :func:`simulate`."""
     platform = platform or M.PlatformConfig()
     att_start = att_finish = None
     ctrl_times = ctrl_caps = None
@@ -1098,7 +1151,7 @@ def simulate_to_trace(wl: M.Workload, platform: Optional[M.PlatformConfig] = Non
                        else jnp.asarray(ctrl, jnp.float32),
                        fail_holds_frac=None if frac >= 1.0 else frac,
                        n_ctrl_slots=n_ctrl if n_ctrl > 0 else None,
-                       **fleet_kw)
+                       admission_sort=admission_sort, **fleet_kw)
         caps0 = np.asarray(scenario.cap_vals[0], np.int64)
         attempts = np.asarray(res["attempts"], np.int64)
         completed = np.asarray(res["done"])
@@ -1119,7 +1172,7 @@ def simulate_to_trace(wl: M.Workload, platform: Optional[M.PlatformConfig] = Non
     else:
         vwl = VWorkload.from_workload(wl, platform)
         res = simulate(vwl, jnp.asarray(platform.capacities, jnp.int32),
-                       policy, **fleet_kw)
+                       policy, admission_sort=admission_sort, **fleet_kw)
         caps0 = platform.capacities
         attempts = None
         completed = np.asarray(res["done"]) if fl is not None else None
@@ -1171,7 +1224,7 @@ def simulate_ensemble(arrival, n_tasks, task_res, service, priority,
                       backoff=None, policies=None, attempt_service=None,
                       n_attempt_slots: Optional[int] = None,
                       controllers=None, fail_holds_frac=None,
-                      admission_sort: str = "fused",
+                      admission_sort: str = "select",
                       n_ctrl_slots: Optional[int] = None,
                       fleets=None, trig=None, obs_noise=None, drift_inc=None,
                       pool_gain=None, pool_base=None, n_pool_eff=None,
@@ -1187,14 +1240,14 @@ def simulate_ensemble(arrival, n_tasks, task_res, service, priority,
     ``attempt_service [R, N, T, A]`` (per-attempt resampled service times),
     ``controllers [R, C]`` (closed-loop ControllerParams rows; an all-zero
     row disables the controller for that replica), ``fail_holds_frac [R]``
-    (slot-holding fraction of failing attempts) — let one SPMD call A/B
+    (slot-holding fraction of failing attempts) — let one batched call A/B
     capacity-planning *and* autoscaler/controller/failure scenarios across
     the replica axis. ``policies [R]`` (i32) assigns a (possibly different)
     admission policy per replica via the traced ``policy_dyn`` path, so a
     whole experiment grid — capacities, scenarios, controller gains, *and*
     schedulers — lowers to this one jit+vmap call. ``n_attempt_slots``
     (static) turns on per-attempt start/finish recording;
-    ``admission_sort`` (static) selects the fused or chained ranking;
+    ``admission_sort`` (static) selects the ranking as in :func:`simulate`;
     ``n_ctrl_slots`` (static; the max :func:`repro.core.des.ctrl_tick_bound`
     over the batch) turns on realized-capacity-timeline recording — the
     per-replica action buffers come back stacked ``ctrl_act [R, E, 1+nres]``

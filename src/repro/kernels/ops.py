@@ -1,9 +1,9 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels execute in interpret mode — the kernel
-body runs in Python for correctness validation; on TPU they compile to
-Mosaic. ``KERNEL_INTERPRET`` flips automatically from the backend, and can be
-forced via the REPRO_KERNEL_INTERPRET env var.
+Off-TPU the kernels execute in interpret mode — the kernel body runs as
+ordinary XLA ops for correctness validation; on TPU they compile to Mosaic.
+``interpret=None`` picks from the backend; pass ``interpret=True`` to force
+the interpreter.
 """
 from __future__ import annotations
 

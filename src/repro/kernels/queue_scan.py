@@ -4,13 +4,13 @@ Two kernels share this module:
 
 ``queue_scan`` — c-server FIFO queue scan. Given per-resource job streams
 sorted by ready time, computes exact start / finish times of an M/G/c FIFO
-station: the carry is the vector of the c earliest server-free times, held
-in VMEM; each job takes the min slot. Grid = (n_queues,) — one program per
-(resource x replica), so a Monte-Carlo capacity sweep of thousands of
-stations runs as one kernel launch. The inner loop is argmin + masked
-update over a (c,)-vector — VPU work, not MXU; the win over the host
-engine is batching queues across the grid and keeping the whole job stream
-in VMEM. Oracle: :func:`repro.core.des.single_station_fifo`.
+station: the carry is each queue's c server-free times, held in VMEM; each
+job takes the earliest-free server. Queues lie on the 128 lanes and jobs on
+sublanes, so one program advances 128 stations (resource x replica) in
+lockstep, and the job stream passes through VMEM in blocks with the
+server-free times carried in scratch. The inner step is a min + masked
+update over a (c, 128) tile — VPU work, not MXU. Oracle:
+:func:`repro.core.des.single_station_fifo`.
 
 ``fused_admission`` — ONE ranked admission round of the wave loop
 (``vdes._admission_stage``), fused: lexicographic rank over
@@ -31,16 +31,15 @@ admission_sort="pallas")``; parity with the ``"fused"`` / ``"chained"`` /
 ``"dense"`` paths is asserted by tests and gated by
 ``artifacts/BENCH_kernels.json: pallas_vs_lax_admission_drift``.
 
-Both kernels auto-fallback to ``interpret=True`` off-TPU (the container's
-CPU included), overridable via the ``REPRO_KERNEL_INTERPRET`` env var or
-the explicit ``interpret`` kwarg — kernel bodies then run through the
-Pallas interpreter as ordinary traceable XLA ops, so they work under
-``jit``/``vmap``/``lax.while_loop`` on any backend.
+Both kernels run in interpret mode exactly when the backend is not a TPU
+— kernel bodies then run through the Pallas interpreter as ordinary
+traceable XLA ops, so they work under ``jit``/``vmap``/``lax.while_loop``
+on the CPU. On a TPU they compile; only an explicit ``interpret=True``
+argument, which no engine path passes, interprets there.
 """
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -49,63 +48,85 @@ from jax.experimental.pallas import tpu as pltpu
 
 # lane width: pad job axes to a multiple of this (f32 min tile is (8, 128))
 _LANES = 128
+# sublane count of an f32 tile
+_SUBLANES = 8
+# queue_scan job-block height: the job stream streams through VMEM in
+# blocks of this many jobs, the server-free times carried in scratch
+_JOB_BLOCK = 512
 
 
 def _auto_interpret() -> bool:
-    """Interpret kernels off-TPU (overridable via REPRO_KERNEL_INTERPRET) —
-    the canonical backend check, shared with :mod:`repro.kernels.ops`."""
-    env = os.environ.get("REPRO_KERNEL_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
+    """Interpret kernels exactly when the backend is not a TPU — the
+    canonical backend check, shared with :mod:`repro.kernels.ops`."""
     return jax.default_backend() != "tpu"
 
 
 # ------------------------------------------------------------ queue_scan
 
 def _queue_kernel(ready_ref, service_ref, start_ref, finish_ref, slots_ref,
-                  *, n_jobs: int, capacity: int):
-    slots_ref[...] = jnp.zeros_like(slots_ref)
+                  *, capacity: int):
+    """One (job block x 128 queues) tile: jobs on sublanes, one queue per
+    lane. ``slots_ref [cap_pad, 128]`` holds each queue's server-free
+    times across job blocks; padding slots are +inf and never the min."""
+    cap_pad = slots_ref.shape[0]
+    sid = jax.lax.broadcasted_iota(jnp.int32, (cap_pad, _LANES), 0)
 
-    def body(j, _):
-        slots = slots_ref[...]                       # [1, capacity]
-        k = jnp.argmin(slots[0, :])
-        r = ready_ref[0, j]
-        s = jnp.maximum(r, slots[0, k])
-        f = s + service_ref[0, j]
-        start_ref[0, j] = s
-        finish_ref[0, j] = f
-        idx = jax.lax.broadcasted_iota(jnp.int32, (1, capacity), 1)
-        slots_ref[...] = jnp.where(idx == k, f, slots)
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        slots_ref[...] = jnp.where(sid < capacity, 0.0,
+                                   jnp.inf).astype(jnp.float32)
+
+    def chunk(c, _):
+        # one aligned (8, 128) tile of jobs per step; its rows in order
+        base = pl.multiple_of(c * _SUBLANES, _SUBLANES)
+        rdy = ready_ref[pl.ds(base, _SUBLANES), :]
+        svc = service_ref[pl.ds(base, _SUBLANES), :]
+        slots = slots_ref[...]
+        starts, finishes = [], []
+        for i in range(_SUBLANES):
+            free_at = jnp.min(slots, axis=0, keepdims=True)      # [1, 128]
+            k = jnp.min(jnp.where(slots == free_at, sid, cap_pad), axis=0,
+                        keepdims=True)                           # first min
+            s = jnp.maximum(rdy[i:i + 1, :], free_at)
+            f = s + svc[i:i + 1, :]
+            starts.append(s)
+            finishes.append(f)
+            slots = jnp.where(sid == k, f, slots)
+        slots_ref[...] = slots
+        start_ref[pl.ds(base, _SUBLANES), :] = jnp.concatenate(starts, 0)
+        finish_ref[pl.ds(base, _SUBLANES), :] = jnp.concatenate(finishes, 0)
         return 0
 
-    jax.lax.fori_loop(0, n_jobs, body, 0)
+    jax.lax.fori_loop(0, ready_ref.shape[0] // _SUBLANES, chunk, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("capacity", "interpret"))
 def _queue_scan_call(ready, service, *, capacity: int, interpret: bool):
     R, N = ready.shape
-    kernel = functools.partial(_queue_kernel, n_jobs=N, capacity=capacity)
+    jb = min(_JOB_BLOCK, -(-N // _SUBLANES) * _SUBLANES)
+    n_pad = -(-N // jb) * jb
+    q_pad = -(-R // _LANES) * _LANES
+    cap_pad = -(-capacity // _SUBLANES) * _SUBLANES
+
+    # [R, N] -> [N, R]: queues on lanes. Padding jobs trail every real job
+    # of their queue, so FIFO never lets them move a real start.
+    def lay(x):
+        return jnp.pad(x.astype(jnp.float32).T, ((0, n_pad - N),
+                                                 (0, q_pad - R)))
+
+    spec = pl.BlockSpec((jb, _LANES), lambda q, j: (j, q))
     start, finish = pl.pallas_call(
-        kernel,
-        grid=(R,),
-        in_specs=[
-            pl.BlockSpec((1, N), lambda r: (r, 0)),
-            pl.BlockSpec((1, N), lambda r: (r, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, N), lambda r: (r, 0)),
-            pl.BlockSpec((1, N), lambda r: (r, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((R, N), jnp.float32),
-            jax.ShapeDtypeStruct((R, N), jnp.float32),
-        ],
-        # 2D scratch: f32 VMEM tiles are (8, 128)-aligned, a bare (c,)
-        # vector is not a legal TPU layout
-        scratch_shapes=[pltpu.VMEM((1, capacity), jnp.float32)],
+        functools.partial(_queue_kernel, capacity=capacity),
+        grid=(q_pad // _LANES, n_pad // jb),
+        in_specs=[spec, spec],
+        out_specs=[spec, spec],
+        out_shape=[jax.ShapeDtypeStruct((n_pad, q_pad), jnp.float32)] * 2,
+        scratch_shapes=[pltpu.VMEM((cap_pad, _LANES), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(ready.astype(jnp.float32), service.astype(jnp.float32))
-    return start, finish
+    )(lay(ready), lay(service))
+    return start[:N, :R].T, finish[:N, :R].T
 
 
 def queue_scan(ready: jnp.ndarray, service: jnp.ndarray, *, capacity: int,
@@ -113,7 +134,7 @@ def queue_scan(ready: jnp.ndarray, service: jnp.ndarray, *, capacity: int,
     """ready, service: [R, N] (sorted by ready within each row).
     Returns (start, finish): [R, N] f32 — exact M/G/c FIFO station times
     (oracle: :func:`repro.core.des.single_station_fifo` per row).
-    ``interpret=None`` auto-falls back to the Pallas interpreter off-TPU."""
+    ``interpret=None`` interprets exactly when the backend is not a TPU."""
     if interpret is None:
         interpret = _auto_interpret()
     return _queue_scan_call(ready, service, capacity=capacity,
@@ -194,8 +215,8 @@ def fused_admission(res_q: jnp.ndarray, pkey: jnp.ndarray,
     or service time for SJF); ``enq_wave [N]`` i32 — FIFO tie-break wave
     counter; ``free [nres]`` i32 — free slots per resource. Bit-identical
     to the ``lax.sort`` ranking in ``vdes._admission_stage`` (see module
-    docstring for the seat-count argument). ``interpret=None`` auto-falls
-    back to the Pallas interpreter off-TPU."""
+    docstring for the seat-count argument). ``interpret=None`` interprets
+    exactly when the backend is not a TPU."""
     if interpret is None:
         interpret = _auto_interpret()
     return _fused_admission_call(res_q, pkey, enq_wave, free,

@@ -1,9 +1,11 @@
 """PipeSim experiment launcher (the paper's CLI entry point).
 
-Fits simulation parameters from (generated) empirical traces, runs an
-experiment or a sweep, prints the analytics summary.
+Loads the fitted simulation parameters (``artifacts/pipesim_params.npz``,
+or fits them from generated empirical traces when the file is missing),
+runs one experiment, prints the analytics summary. On ``--engine jax``
+(the default) the summary names the device the engine ran on.
 
-  PYTHONPATH=src python -m repro.launch.simulate --days 2 --horizon-days 1 \
+  PYTHONPATH=src python -m repro.launch.simulate --horizon-days 1 \
       --learning-capacity 8 --policy sjf
 """
 from __future__ import annotations
@@ -13,12 +15,16 @@ import json
 import os
 
 import jax
-import numpy as np
 
 from repro.core import (ExperimentSpec, PlatformConfig, ResourceConfig,
                         fit_simulation_params, generate_empirical_workload,
                         run_experiment)
 from repro.core.des import POLICY_NAMES
+from repro.launch.compile_cache import enable_compile_cache
+
+PARAMS_PATH = os.path.abspath(os.path.join(
+    os.path.dirname(__file__), "..", "..", "..", "artifacts",
+    "pipesim_params.npz"))
 
 
 def main():
@@ -30,12 +36,13 @@ def main():
     ap.add_argument("--compute-capacity", type=int, default=48)
     ap.add_argument("--learning-capacity", type=int, default=32)
     ap.add_argument("--policy", default="fifo", choices=POLICY_NAMES)
-    ap.add_argument("--engine", default="numpy", choices=["numpy", "jax"])
+    ap.add_argument("--engine", default="jax", choices=["numpy", "jax"])
     ap.add_argument("--replicas", type=int, default=1)
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--params-cache", default="/tmp/pipesim_params.npz")
+    ap.add_argument("--params-cache", default=PARAMS_PATH)
     ap.add_argument("--out", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     from repro.core.fitting import SimulationParams
     if os.path.exists(args.params_cache):
@@ -63,7 +70,13 @@ def main():
         engine=args.engine,
     )
     res = run_experiment(exp, params)
-    print(json.dumps(res.summary, indent=2, default=float))
+    summary = dict(res.summary)
+    if args.engine == "jax":
+        dev = jax.devices()[0]
+        summary["device"] = {"platform": dev.platform,
+                             "kind": dev.device_kind,
+                             "count": len(jax.devices())}
+    print(json.dumps(summary, indent=2, default=float))
     if args.out:
         res.save(args.out)
         print(f"[saved] {args.out}")

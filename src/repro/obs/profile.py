@@ -64,12 +64,8 @@ def profile_compile_execute(wl, platform, policy: int = des.POLICY_FIFO,
     ``compile_s`` is the cold-call overhead (first call minus a warm call):
     trace + lowering + XLA compile. Cleared caches make the first call
     genuinely cold even when the surrounding process already ran the
-    engine (older jax without ``clear_caches`` degrades gracefully:
-    ``compile_s`` then reports ~0 for pre-warmed shapes)."""
-    try:
-        jax.clear_caches()
-    except AttributeError:      # older jax: cache may already be warm
-        pass
+    engine."""
+    jax.clear_caches()
 
     def run():
         return vdes.simulate_to_trace(wl, platform, policy,

@@ -397,7 +397,7 @@ def stream_simulate(
         max_blocks: Optional[int] = None,
         overlap: bool = True,
         min_rows: int = 64,
-        admission_sort: str = "fused",
+        admission_sort: str = "select",
         sink: Optional[Callable[[trace.TaskRecords], None]] = None,
         plan_out: Optional[list] = None) -> StreamResult:
     """Stream a :class:`TraceSource` through the batched engine in arrival
@@ -654,7 +654,7 @@ def oneshot_reference(
         scenario=None, fleet=None, trigger=None, probe=None,
         horizon_s: float = 7 * 86400.0, seed: int = 0, params=None,
         max_blocks: Optional[int] = None,
-        admission_sort: str = "fused") -> Dict:
+        admission_sort: str = "select") -> Dict:
     """Materialize the ENTIRE stream — identical per-block draws to the
     windowed driver — into one ``vdes.simulate_ensemble`` call. Returns
     the sorted records plus the operational timelines, keyed like

@@ -1,10 +1,6 @@
 import os
 import sys
 
-# Tests run single-device (the dry-run sets its own 512-device flag in a
-# separate process); keep kernel tests in interpret mode on CPU.
-os.environ.setdefault("REPRO_KERNEL_INTERPRET", "1")
-
 # make the top-level benchmarks/ package importable regardless of cwd
 sys.path.insert(0, os.path.abspath(os.path.join(os.path.dirname(__file__),
                                                 "..")))

@@ -252,10 +252,14 @@ def test_unguarded_div_triggers_guarded_passes(tmp_path):
             lambda c: c < 10.0,
             lambda c: c / guarded_denominator(d - 1.0, xp=jnp), x)
 
-    fs = audit_closed_jaxpr(_trace(bad, 1.0, 3.0), str(tmp_path), "synth")
-    assert "unguarded-div" in rules_of(fs)
-    fs = audit_closed_jaxpr(_trace(good, 1.0, 3.0), str(tmp_path), "synth")
+    # f32 operands, as in the engine: bare Python floats would seed a
+    # weak-typed carry, a separate finding (carry-weak-type)
+    x, d = jnp.float32(1.0), jnp.float32(3.0)
+    fs = audit_closed_jaxpr(_trace(bad, x, d), str(tmp_path), "synth")
+    assert rules_of(fs) == ["unguarded-div"]
+    fs = audit_closed_jaxpr(_trace(good, x, d), str(tmp_path), "synth")
     assert "unguarded-div" not in rules_of(fs)
+    assert rules_of(fs) == []
 
 
 def test_unguarded_log_triggers_clamped_passes(tmp_path):
@@ -278,7 +282,7 @@ def test_carry_f64_caught_under_x64(tmp_path):
     def f(x):
         return jax.lax.while_loop(lambda c: c < 10.0, lambda c: c + 1.0, x)
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         closed = jax.make_jaxpr(f)(jnp.float64(0.0))
     fs = audit_carry_only(closed, str(tmp_path), "synth[x64]")
     assert rules_of(fs) == ["carry-f64"]
@@ -301,7 +305,7 @@ def test_f64_const_conversion_caught(tmp_path):
     def f(x):
         return (x.astype(jnp.float64) * 2.0).astype(jnp.float32)
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         closed = jax.make_jaxpr(f)(jnp.float32(1.0))
     fs = audit_closed_jaxpr(closed, str(tmp_path), "synth[x64]")
     assert "f64-const" in rules_of(fs)

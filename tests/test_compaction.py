@@ -94,7 +94,10 @@ def _assert_all_paths_agree(res_q, pkey, wave, free):
                                  res_q, pkey, wave, free)
     a_dense = np.asarray(vdes.admission_mask_dense(
         res_q, pkey, wave, free))
+    a_select = np.asarray(vdes.admission_mask_select(
+        res_q, pkey, wave, free))
     a_pallas = np.asarray(fused_admission(res_q, pkey, wave, free))
+    assert np.array_equal(a_select, ref)
     assert np.array_equal(a_fused, ref)
     assert np.array_equal(a_chain, ref)
     assert np.array_equal(a_dense, ref)
@@ -124,13 +127,17 @@ def test_admission_fifo_skip_pkey_identical():
         fast = np.asarray(vdes.admission_mask_dense(res_q, pkey, wave, free,
                                                     skip_pkey=True))
         assert np.array_equal(full, fast)
+        sel = np.asarray(vdes.admission_mask_select(res_q, pkey, wave, free,
+                                                    skip_pkey=True))
+        assert np.array_equal(full, sel)
 
 
 @settings(max_examples=30, deadline=None)
 @given(seed=st.integers(0, 2**32 - 1), n=st.integers(1, 250))
 def test_admission_paths_agree_property(seed, n):
     """pallas(interpret) == fused lax.sort == chained argsorts == dense
-    mask == numpy reference on arbitrary admission rounds."""
+    mask == lex-min select == numpy reference on arbitrary admission
+    rounds."""
     _assert_all_paths_agree(*_round_case(seed, n))
 
 

@@ -246,6 +246,28 @@ def test_fused_sort_full_sim_equivalence(rng, policy):
     assert int(rf["waves"]) == int(rc["waves"])
 
 
+@pytest.mark.parametrize("policy", [des.POLICY_FIFO, des.POLICY_SJF,
+                                    des.POLICY_PRIORITY])
+def test_select_full_sim_equivalence(rng, policy):
+    """The default lex-min ranking replays the fused sort's schedule
+    wave for wave, with retries and a capacity schedule in play."""
+    wl = int_workload(rng, n=150)
+    plat = platform()
+    v = vdes.VWorkload.from_workload(wl, plat)
+    v = dataclasses.replace(v, attempts=jnp.asarray(
+        rng.integers(1, 3, np.shape(v.service)), jnp.int32))
+    caps = jnp.asarray(plat.capacities, jnp.int32)
+    kw = dict(cap_times=jnp.asarray([0.0, 200.0], jnp.float32),
+              cap_vals=jnp.asarray([caps, caps + 2], jnp.int32),
+              backoff=jnp.asarray([5.0, 2.0, 40.0], jnp.float32))
+    rf = vdes.simulate(v, caps, policy, admission_sort="fused", **kw)
+    rs = vdes.simulate(v, caps, policy, admission_sort="select", **kw)
+    for k in ("start", "finish", "ready", "attempts"):
+        assert np.array_equal(np.asarray(rf[k]), np.asarray(rs[k]),
+                              equal_nan=True), k
+    assert int(rf["waves"]) == int(rs["waves"])
+
+
 def test_fused_sort_traced_policy_dyn_equivalence(rng):
     """The traced-policy path (vmapped heterogeneous schedulers) uses the
     same fused ranking."""
