@@ -1,6 +1,6 @@
 """Telemetry-plane acceptance bench (``artifacts/BENCH_obs.json``).
 
-Four measurements, one report:
+Three measurements, one report:
 
   1. **Probe parity** (``probe_parity_drift``, gated at exactly 0.0 by
      ``check_drift.py``): a fully-loaded program — closed-loop controller +
@@ -13,9 +13,6 @@ Four measurements, one report:
      criterion), and the JSONL export must parse back equal.
   3. **Self-profile**: compile-vs-execute wall split of the JAX engine and
      waves/s for BOTH engines on the same program.
-  4. **Per-stage attribution**: differential-ablation cost of each optional
-     kernel stage (control / fleet / probe) over the
-     select+completion+admission core, per wave.
 
 ``REPRO_BENCH_SMOKE=1`` (or ``--smoke``) shrinks the horizon for CI.
 
@@ -45,8 +42,8 @@ from repro.core.synthesizer import synthesize_workload
 from repro.obs import (ProbeSpec, attempt_intervals_from_records,
                        build_spans, compile_probe, profile_compile_execute,
                        profile_numpy, read_chrome_attempt_intervals,
-                       read_spans_jsonl, stage_attribution,
-                       write_chrome_trace, write_spans_jsonl)
+                       read_spans_jsonl, write_chrome_trace,
+                       write_spans_jsonl)
 from repro.ops import ReactiveController, Scenario
 from repro.ops.scenario import compile_fleet
 
@@ -131,10 +128,6 @@ def rows():
                                       probe=probe,
                                       repeats=1 if smoke else 3)
 
-    # --- 4. per-stage attribution by differential ablation
-    stages = stage_attribution(ext, plat, scenario=comp, fleet=cf,
-                               probe=probe, repeats=1 if smoke else 3)
-
     report = {
         "pipelines": wl.n,
         "horizon_s": horizon,
@@ -150,9 +143,6 @@ def rows():
         "jax_execute_s": prof_jx["execute_s"],
         "jax_waves_per_s": prof_jx["waves_per_s"],
         "waves": prof_jx["waves"],
-        "stage_attribution_us_per_wave": {
-            k: v["per_wave_us"] for k, v in stages.items()},
-        "stage_walls_s": {k: v["wall_s"] for k, v in stages.items()},
         "smoke": smoke,
     }
     os.makedirs(os.path.dirname(OUT_PATH), exist_ok=True)
@@ -169,8 +159,6 @@ def rows():
         ("obs_jax_engine", prof_jx["execute_s"] * 1e6,
          f"{prof_jx['waves_per_s']:.0f}waves/s_compile"
          f"{prof_jx['compile_s']:.1f}s"),
-        ("obs_stage_probe", stages.get("probe", {}).get("per_wave_us", 0.0),
-         "us_per_wave_delta"),
     ]
 
 

@@ -166,8 +166,10 @@ def compare_fields(a, b, names) -> tuple:
 
 def compare_traces(a: M.SimTrace, b: M.SimTrace) -> tuple:
     """Every SimTrace buffer: schedules, attempts, controller, reliability,
-    fleet and probe buffers, and the wave count."""
-    return compare_fields(a, b, [f.name for f in dataclasses.fields(a)])
+    fleet and probe buffers, and the wave count. Not the batched engine's
+    ``ops_waves``, a diagnostic the other engines do not keep."""
+    return compare_fields(a, b, [f.name for f in dataclasses.fields(a)
+                                 if f.name != "ops_waves"])
 
 
 def sorted_records(rec):

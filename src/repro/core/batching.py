@@ -330,5 +330,6 @@ def batch_trace(out: dict, idx: int, wl: M.Workload,
         ctrl_times=ctrl_times,
         ctrl_caps=ctrl_caps,
         waves=int(out["waves"][idx]) if "waves" in out else None,
+        ops_waves=int(out["ops_waves"][idx]) if "ops_waves" in out else None,
         **fl_cols,
     )

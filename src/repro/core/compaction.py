@@ -335,6 +335,8 @@ def simulate_ensemble_compacted(
     res = dict(start=st["start"], finish=st["finish"], ready=st["ready"],
                attempts=st["att_out"], done=st["phase"] == _DONE,
                waves=st["wave"])
+    if "ops_waves" in st:
+        res["ops_waves"] = st["ops_waves"]
     if statics["n_attempt_slots"] is not None:
         res["att_start"] = st["att_start"]
         res["att_finish"] = st["att_finish"]

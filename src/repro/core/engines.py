@@ -33,6 +33,7 @@ import numpy as np
 
 from repro.core import batching, des, trace, vdes
 from repro.core.synthesizer import synthesize_workload
+from repro.obs import profile
 
 
 @runtime_checkable
@@ -399,45 +400,111 @@ class JaxEngine:
         workloads with differing ``max_tasks``) warn and fall back to the
         exact numpy serial loop."""
         t0 = time.perf_counter()
-        nres = {len(s.platform.resources) for s in specs}
-        exec_specs = list(specs)
-        if len(nres) != 1:
-            # ragged platform grid: pad every point to the superset so ONE
-            # rectangular batch still covers the grid (results/summaries
-            # are computed against each point's own unpadded platform)
-            nres_max = max(nres)
-            exec_specs = [
-                dataclasses.replace(s, platform=_pad_platform(s.platform,
-                                                              nres_max))
-                for s in specs]
+        # every statement below runs inside one of the layer spans
+        # (repro.obs.profile.span), which nest under Sweep.run's "sweep"
+        with profile.span("prep"):
+            nres = {len(s.platform.resources) for s in specs}
+            exec_specs = list(specs)
+            if len(nres) != 1:
+                # ragged platform grid: pad every point to the superset so
+                # ONE rectangular batch still covers the grid (results and
+                # summaries are computed against each point's own unpadded
+                # platform)
+                nres_max = max(nres)
+                exec_specs = [
+                    dataclasses.replace(
+                        s, platform=_pad_platform(s.platform, nres_max))
+                    for s in specs]
 
-        entries = []  # (spec index, workload, compiled, fleet, probe, rel)
-        wl_cache = {}   # distinct workloads synthesized once for the grid
-        for g, spec in enumerate(exec_specs):
-            wls, compiled, fleets, probe, rels = _spec_workloads(
-                spec, params, cache=wl_cache)
-            for r, w in enumerate(wls):
-                entries.append(
-                    (g, w, compiled[r] if compiled is not None else None,
-                     fleets[r] if fleets is not None else None, probe,
-                     rels[r] if rels is not None else None))
+            entries = []  # (spec index, workload, compiled, fleet, probe, rel)
+            wl_cache = {}   # distinct workloads synthesized once for the grid
+            for g, spec in enumerate(exec_specs):
+                wls, compiled, fleets, probe, rels = _spec_workloads(
+                    spec, params, cache=wl_cache)
+                for r, w in enumerate(wls):
+                    entries.append(
+                        (g, w, compiled[r] if compiled is not None else None,
+                         fleets[r] if fleets is not None else None, probe,
+                         rels[r] if rels is not None else None))
 
-        plats = [exec_specs[g].platform for g, *_ in entries]
-        try:
-            cols = batching.pad_workloads([w for _, w, *_ in entries],
-                                          plats)
-        except ValueError as e:          # genuinely incompatible grid
-            warnings.warn(
-                f"sweep grid cannot lower to one rectangular batch ({e}); "
-                "falling back to the exact numpy serial loop",
-                RuntimeWarning, stacklevel=2)
+        with profile.span("batching"):
+            plats = [exec_specs[g].platform for g, *_ in entries]
+            try:
+                cols = batching.pad_workloads([w for _, w, *_ in entries],
+                                              plats)
+            except ValueError as e:      # genuinely incompatible grid
+                warnings.warn(
+                    f"sweep grid cannot lower to one rectangular batch ({e}); "
+                    "falling back to the exact numpy serial loop",
+                    RuntimeWarning, stacklevel=2)
+                cols = None
+            if cols is not None:
+                n_max = cols.pop("n_max")
+                caps = np.stack([p.capacities for p in plats]).astype(
+                    np.int32)
+                pol = np.array([exec_specs[g].policy for g, *_ in entries],
+                               np.int32)
+                uniform_policy = bool((pol == pol[0]).all())
+                stacked = self._stack(exec_specs, specs, entries, cols, n_max)
+        if cols is None:
             return get_engine("numpy").run_sweep(specs, params)
-        n_max = cols.pop("n_max")
-        caps = np.stack([p.capacities for p in plats]).astype(np.int32)
-        pol = np.array([exec_specs[g].policy for g, *_ in entries],
-                       np.int32)
-        uniform_policy = bool((pol == pol[0]).all())
 
+        with profile.span("upload"):
+            inputs = [jax.numpy.asarray(cols[k]) for k in
+                      ("arrival", "n_tasks", "task_res", "service",
+                       "priority")] + [jax.numpy.asarray(caps)]
+        with profile.span("engine"):
+            # wait here, so the fetch below times the copy alone
+            out = jax.block_until_ready(self._ensemble(
+                *inputs, int(pol[0]),
+                policies=None if uniform_policy else pol, **stacked))
+        with profile.span("fetch"):
+            out = {k: np.asarray(v) for k, v in out.items()}
+        wall = time.perf_counter() - t0
+
+        results, i = [], 0
+        for g, spec in enumerate(specs):
+            with profile.span("summaries"):
+                recs, sums = [], []
+                last_tr = None
+                for r in range(spec.n_replicas):
+                    _, wl, comp, fl, pr, rl = entries[i + r]
+                    tr = batching.batch_trace(out, i + r, wl,
+                                              spec.platform.capacities,
+                                              with_scenario=comp is not None,
+                                              fleet=fl, probe=pr,
+                                              reliability=rl)
+                    last_tr = tr
+                    rec = trace.flatten_trace(tr, wl)
+                    recs.append(rec)
+                    # summarize against the executed (possibly padded)
+                    # platform so cost/schedule tensors line up; padded
+                    # pools contribute zero everywhere
+                    sums.append(_summarize(exec_specs[g], rec, comp, tr,
+                                           rel=rl))
+                i += spec.n_replicas
+                if spec.n_replicas > 1:
+                    res = _aggregate_replicas(spec, sums, recs, wall)
+            with profile.span("results"):
+                if spec.n_replicas == 1:
+                    from repro.core.experiment import ExperimentResult
+                    from repro.core.runtime import lifecycle_result
+                    summary = sums[0]
+                    summary["wall_s"] = wall   # the whole grid's wall clock
+                    summary["pipelines_per_s"] = \
+                        summary["n_pipelines"] / max(wall, 1e-9)
+                    res = ExperimentResult(
+                        spec, summary, recs[0], wall,
+                        lifecycle=lifecycle_result(last_tr),
+                        timeline=_probe_timeline(spec, last_tr),
+                        trace=last_tr)
+                results.append(res)
+        return results
+
+    @staticmethod
+    def _stack(exec_specs, specs, entries, cols, n_max) -> dict:
+        """The ensemble call's keyword tensors for every entry: compiled
+        scenarios, fleets, probes and reliability timelines."""
         scen_kw = {}
         if any(c is not None for _, _, c, _, _, _ in entries):
             from repro.ops.scenario import CompiledScenario
@@ -467,50 +534,7 @@ class JaxEngine:
         # with and without reliability share the one batch
         rel_kw = batching.stack_reliability(
             [rl for _, _, _, _, _, rl in entries])
-
-        out = self._ensemble(
-            *[jax.numpy.asarray(cols[k]) for k in
-              ("arrival", "n_tasks", "task_res", "service", "priority")],
-            jax.numpy.asarray(caps), int(pol[0]),
-            policies=None if uniform_policy else pol, **scen_kw, **fleet_kw,
-            **probe_kw, **rel_kw)
-        out = {k: np.asarray(v) for k, v in out.items()}
-        wall = time.perf_counter() - t0
-
-        results, i = [], 0
-        for g, spec in enumerate(specs):
-            recs, sums = [], []
-            last_tr = None
-            for r in range(spec.n_replicas):
-                _, wl, comp, fl, pr, rl = entries[i + r]
-                tr = batching.batch_trace(out, i + r, wl,
-                                          spec.platform.capacities,
-                                          with_scenario=comp is not None,
-                                          fleet=fl, probe=pr,
-                                          reliability=rl)
-                last_tr = tr
-                rec = trace.flatten_trace(tr, wl)
-                recs.append(rec)
-                # summarize against the executed (possibly padded) platform
-                # so cost/schedule tensors line up; padded pools contribute
-                # zero everywhere
-                sums.append(_summarize(exec_specs[g], rec, comp, tr,
-                                       rel=rl))
-            i += spec.n_replicas
-            if spec.n_replicas == 1:
-                from repro.core.experiment import ExperimentResult
-                from repro.core.runtime import lifecycle_result
-                summary = sums[0]
-                summary["wall_s"] = wall   # the whole grid's wall clock
-                summary["pipelines_per_s"] = \
-                    summary["n_pipelines"] / max(wall, 1e-9)
-                results.append(ExperimentResult(
-                    spec, summary, recs[0], wall,
-                    lifecycle=lifecycle_result(last_tr),
-                    timeline=_probe_timeline(spec, last_tr), trace=last_tr))
-            else:
-                results.append(_aggregate_replicas(spec, sums, recs, wall))
-        return results
+        return {**scen_kw, **fleet_kw, **probe_kw, **rel_kw}
 
 
 class JaxCompactEngine(JaxEngine):

@@ -35,6 +35,7 @@ from repro.core import des, trace
 from repro.core import model as M
 from repro.core.fitting import SimulationParams
 from repro.core.runtime import FleetSpec, TriggerSpec
+from repro.obs import profile
 from repro.ops.scenario import Scenario
 
 _UNSET = object()   # sentinel: "controller" axis absent vs explicitly None
@@ -275,13 +276,15 @@ class Sweep:
     def run(self, params: Optional[SimulationParams] = None
             ) -> List[ExperimentResult]:
         from repro.core.engines import get_engine
-        specs = self.points()
-        # an "engine" axis dispatches each point on its own backend (each
-        # engine still batches its own group); order is preserved
-        results: List[Optional[ExperimentResult]] = [None] * len(specs)
-        for name in dict.fromkeys(s.engine for s in specs):
-            idx = [i for i, s in enumerate(specs) if s.engine == name]
-            for i, r in zip(idx, get_engine(name).run_sweep(
-                    [specs[i] for i in idx], params)):
-                results[i] = r
+        with profile.span("sweep"):
+            with profile.span("points"):
+                specs = self.points()
+            # an "engine" axis dispatches each point on its own backend
+            # (each engine still batches its own group); order is preserved
+            results: List[Optional[ExperimentResult]] = [None] * len(specs)
+            for name in dict.fromkeys(s.engine for s in specs):
+                idx = [i for i, s in enumerate(specs) if s.engine == name]
+                for i, r in zip(idx, get_engine(name).run_sweep(
+                        [specs[i] for i in idx], params)):
+                    results[i] = r
         return results

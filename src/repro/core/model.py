@@ -252,6 +252,12 @@ class SimTrace:
     # reporting); both engines retire events in identical waves, so tests
     # assert *wave-for-wave* parity with this, not just equal timestamps
     waves: Optional[int] = None
+    # waves at which an operations event was due (a capacity change,
+    # reliability event, controller, drift or probe tick), counted by the
+    # batched JAX engine in a run with a capacity schedule or an operations
+    # stage. A diagnostic of how the loop spent its waves, not a result: no
+    # summary, record or parity check reads it. None otherwise
+    ops_waves: Optional[int] = None
 
     def action_timeline(self):
         """The SHARED in-engine action timeline: every discrete action an

@@ -47,6 +47,16 @@ Each loop iteration (a **wave**) is composed of up to six named kernel stages:
      through the loop (see :mod:`repro.obs.probes`). Physics-invisible and
      parity-gated: the numpy engine mirrors the sampling op-for-op.
 
+Each stage runs under a ``jax.named_scope`` of its name (``select``,
+``completion``, ``control``, ``admission``, ``fleet``, ``probe``): the op
+names of the compiled program, and so of a profiler trace of it, carry the
+stage, which is how a trace splits a wave's device time by stage. In a
+program with a capacity schedule or an operations stage the carry also
+counts, per replica and from the event minimum the wave already computes,
+the waves at which an operations event was due (``ops_waves``): a capacity
+change, reliability event, controller, drift or probe tick. A redeploy is
+not one: it rides on the wave in which its retrain's last task finishes.
+
 Semantics match ``repro.core.des`` exactly — same wave ordering, same
 FIFO/PRIORITY/SJF keys — verified wave-for-wave by tests on integer-time
 workloads, including under operational scenarios:
@@ -489,6 +499,9 @@ def simulate(vwl: VWorkload, capacities: jnp.ndarray, policy: int = POLICY_FIFO,
         rel_t = jnp.asarray(rel_times, jnp.float32)      # [RV]
         rel_d = jnp.asarray(rel_deltas, jnp.int32)       # [RV, nres]
         RV = n_rel_slots
+    # count the waves at which an operations event was due only where one
+    # can be
+    count_ops = K > 1 or has_rel or has_ctrl or has_fleet or has_probe
 
     state = dict(
         phase=jnp.full((n,), _NOT_ARRIVED, jnp.int32),
@@ -504,6 +517,8 @@ def simulate(vwl: VWorkload, capacities: jnp.ndarray, policy: int = POLICY_FIFO,
         ready=jnp.full((n, T), jnp.nan, jnp.float32),
         att_out=jnp.zeros((n, T), jnp.int32),
     )
+    if count_ops:
+        state["ops_waves"] = jnp.int32(0)
     if n_attempt_slots is not None:
         state["att_start"] = jnp.full((n, T, n_attempt_slots), jnp.nan,
                                       jnp.float32)
@@ -570,20 +585,25 @@ def simulate(vwl: VWorkload, capacities: jnp.ndarray, policy: int = POLICY_FIFO,
     def _select_events(s):
         """Stage 1: the global next-event time. Task events, the next
         scheduled capacity change, the next reliability event, and the next
-        controller tick all participate in the minimum."""
+        controller tick all participate in the minimum. Also returns the
+        next capacity change ``t_cap`` and the next operations event
+        ``t_ops`` (capacity change, reliability event, controller, drift or
+        probe tick), which ``ops_waves`` compares with ``t_star``."""
         t_cap = next_cap_time(s["cap_idx"])
-        t_star = jnp.minimum(jnp.min(s["t_next"]), t_cap)
+        t_ctl = t_cap
         if has_rel:
             ri = jnp.clip(s["rel_idx"], 0, RV - 1)
-            t_rel = jnp.where(s["rel_idx"] < RV, rel_t[ri], INF)
-            t_star = jnp.minimum(t_star, t_rel)
+            t_ctl = jnp.minimum(t_ctl,
+                                jnp.where(s["rel_idx"] < RV, rel_t[ri], INF))
         if has_ctrl:
-            t_star = jnp.minimum(t_star, s["t_eval"])
+            t_ctl = jnp.minimum(t_ctl, s["t_eval"])
+        t_ops = t_ctl
         if has_fleet:
-            t_star = jnp.minimum(t_star, s["t_fleet"])
+            t_ops = jnp.minimum(t_ops, s["t_fleet"])
         if has_probe:
-            t_star = jnp.minimum(t_star, s["t_probe"])
-        return t_star, t_cap
+            t_ops = jnp.minimum(t_ops, s["t_probe"])
+        t_star = jnp.minimum(jnp.min(s["t_next"]), t_ops)
+        return t_star, t_cap, t_ops
 
     def _completion_stage(s, t_star):
         """Stage 2: finishes release slots; failed attempts re-enter the
@@ -1008,7 +1028,7 @@ def simulate(vwl: VWorkload, capacities: jnp.ndarray, policy: int = POLICY_FIFO,
 
     def _running(s, t_star=None):
         if t_star is None:
-            t_star, _ = _select_events(s)
+            t_star = _select_events(s)[0]
         # exit when everything is done OR nothing can ever happen again
         # (e.g. capacity held at zero past the end of the schedule and the
         # controller's evaluation grid is exhausted). Remaining fleet ticks
@@ -1024,8 +1044,9 @@ def simulate(vwl: VWorkload, capacities: jnp.ndarray, policy: int = POLICY_FIFO,
         return alive & (t_star < INF)
 
     def cond(s):
-        t_star, _ = _select_events(s)
-        go = _running(s, t_star)
+        with jax.named_scope("select"):
+            t_star = _select_events(s)[0]
+            go = _running(s, t_star)
         if wave_budget is not None:
             # segment cap: stop at the budget boundary — a wave boundary is
             # a consistent cut, so the compaction driver resumes bit-exactly
@@ -1041,14 +1062,27 @@ def simulate(vwl: VWorkload, capacities: jnp.ndarray, policy: int = POLICY_FIFO,
         return go
 
     def body(s):
-        t_star, t_cap = _select_events(s)
-        s = _completion_stage(s, t_star)
-        s = _control_stage(s, t_star, t_cap)
-        s = _admission_stage(s, t_star)
+        # each stage runs under a named scope: the op names of the
+        # compiled program (and so of a profiler trace) carry the stage,
+        # which splits a wave's device time by stage; no op changes
+        with jax.named_scope("select"):
+            t_star, t_cap, t_ops = _select_events(s)
+        with jax.named_scope("completion"):
+            s = _completion_stage(s, t_star)
+        with jax.named_scope("control"):
+            s = _control_stage(s, t_star, t_cap)
+        with jax.named_scope("admission"):
+            s = _admission_stage(s, t_star)
         if has_fleet:
-            s = _fleet_stage(s, t_star)
+            with jax.named_scope("fleet"):
+                s = _fleet_stage(s, t_star)
         if has_probe:
-            s = _probe_stage(s, t_star)
+            with jax.named_scope("probe"):
+                s = _probe_stage(s, t_star)
+        if count_ops:
+            # an operations event was due at this wave
+            s["ops_waves"] = s["ops_waves"] + (t_ops == t_star).astype(
+                jnp.int32)
         s["wave"] = s["wave"] + 1
         return s
 
@@ -1056,6 +1090,8 @@ def simulate(vwl: VWorkload, capacities: jnp.ndarray, policy: int = POLICY_FIFO,
     res = dict(start=out["start"], finish=out["finish"], ready=out["ready"],
                attempts=out["att_out"], done=out["phase"] == _DONE,
                waves=out["wave"])
+    if count_ops:
+        res["ops_waves"] = out["ops_waves"]
     if n_attempt_slots is not None:
         res["att_start"] = out["att_start"]
         res["att_finish"] = out["att_finish"]

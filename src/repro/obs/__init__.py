@@ -10,8 +10,9 @@ Three parts, one import surface:
     both engines;
   - :mod:`repro.obs.spans` — OTel-style span export of task records and
     in-engine actions, with JSONL and Chrome-trace/Perfetto writers;
-  - :mod:`repro.obs.profile` — the self-profiler: compile-vs-execute
-    split, waves/s for both engines, per-stage cost attribution.
+  - :mod:`repro.obs.profile` — the self-profiler: the program's host
+    spans at a sweep's layer boundaries, compile-vs-execute split, waves/s
+    for both engines.
 """
 from repro.obs.probes import (CompiledProbe, ProbeSpec, ProbeTimeline,
                               compile_probe, probe_channel_names)
@@ -21,7 +22,7 @@ from repro.obs.spans import (attempt_intervals,
                              read_spans_jsonl, write_chrome_trace,
                              write_spans_jsonl)
 from repro.obs.profile import (profile_compile_execute, profile_numpy,
-                               stage_attribution)
+                               span, spans)
 
 __all__ = [
     "ProbeSpec", "CompiledProbe", "ProbeTimeline", "compile_probe",
@@ -29,5 +30,5 @@ __all__ = [
     "build_spans", "write_spans_jsonl", "read_spans_jsonl",
     "write_chrome_trace", "attempt_intervals",
     "attempt_intervals_from_records", "read_chrome_attempt_intervals",
-    "profile_numpy", "profile_compile_execute", "stage_attribution",
+    "profile_numpy", "profile_compile_execute", "span", "spans",
 ]

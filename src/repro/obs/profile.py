@@ -1,37 +1,89 @@
 """Self-profiler: where does the *simulator's* wall time go?
 
 The offline-profiling line of work (PAPERS.md) instruments the system being
-modeled; this module instruments the model. Three measurements every
-benchmark and the ``BENCH_obs.json`` artifact report through:
+modeled; this module instruments the model:
 
+  - :func:`span` — the program's own host spans at the layer boundaries of
+    a sweep (``Sweep.run``: ``sweep`` and the grid's ``points``;
+    ``JaxEngine.run_sweep``: ``prep``, ``batching``, ``upload``,
+    ``engine``, ``fetch``, ``summaries``, ``results``). Each is a
+    ``jax.profiler.TraceAnnotation`` named ``pipesim/<name>``, so under the
+    profiler it lands in the trace on the device's clock, and a record
+    ``(name, parent, sweep, start_ns, end_ns)`` on the
+    ``time.perf_counter_ns`` clock in a bounded buffer that :func:`spans`
+    reads. Always on: a sweep opens a few dozen, and a sweep takes seconds.
+    Inside the device loop the stages carry ``jax.named_scope`` names
+    instead (:mod:`repro.core.vdes`), which split a profiler trace's device
+    time by stage;
   - :func:`profile_compile_execute` — the JAX engine's compile-vs-execute
     wall split (cold first call = trace + XLA lower + compile + run; warm
     calls = run only), plus executed waves and **waves/s**;
   - :func:`profile_numpy` — the reference heap engine's wall and waves/s
     on the same program (the serial baseline every batched speedup is
-    quoted against);
-  - :func:`stage_attribution` — per-stage cost attribution across the wave
-    loop's kernel stages by *differential ablation*: the same workload runs
-    with the optional stages toggled (base = select + completion +
-    admission; then + control, + fleet, + probe), and each stage's
-    per-wave cost is the delta over its baseline. Ablation is the honest
-    way to attribute a fused ``lax.while_loop`` — XLA compiles the wave
-    body as one program, so there is no per-op timeline to read; deltas of
-    measured per-wave costs are what toggling the stage actually buys or
-    costs.
+    quoted against).
 
 All timings take the best of ``repeats`` (minimum — the standard
 noise-floor estimator for microbenchmarks).
 """
 from __future__ import annotations
 
+import collections
+import contextlib
+import itertools
+import threading
 import time
-from typing import Dict, Optional
+from typing import Dict, List, NamedTuple, Optional
 
 import jax
-import numpy as np
 
 from repro.core import des, vdes
+
+#: spans the buffer keeps; the oldest drop first
+SPAN_BUFFER = 4096
+
+
+class Span(NamedTuple):
+    """One closed host span. ``parent`` is the name of the span it opened
+    inside (None at the top); ``sweep`` the id of the ``sweep`` span it
+    belongs to (None outside any)."""
+
+    name: str
+    parent: Optional[str]
+    sweep: Optional[int]
+    start_ns: int
+    end_ns: int
+
+
+_closed = collections.deque(maxlen=SPAN_BUFFER)
+_sweep_ids = itertools.count(1)
+_local = threading.local()          # this thread's open spans, innermost last
+
+
+@contextlib.contextmanager
+def span(name: str):
+    """Time the block as span ``name``: a ``TraceAnnotation`` named
+    ``pipesim/<name>`` and, once it closes, a :class:`Span` in the buffer.
+    A span named ``sweep`` takes a new sweep id; any other takes that of
+    the span it opened inside."""
+    stack = _local.__dict__.setdefault("stack", [])
+    parent = stack[-1] if stack else None
+    sweep = next(_sweep_ids) if name == "sweep" else \
+        (parent[1] if parent else None)
+    stack.append((name, sweep))
+    start = time.perf_counter_ns()
+    try:
+        with jax.profiler.TraceAnnotation(f"pipesim/{name}"):
+            yield
+    finally:
+        end = time.perf_counter_ns()
+        stack.pop()
+        _closed.append(Span(name, parent[0] if parent else None, sweep,
+                            start, end))
+
+
+def spans() -> List[Span]:
+    """The buffered spans, in the order they closed."""
+    return list(_closed)
 
 
 def _best_of(fn, repeats: int) -> float:
@@ -80,45 +132,3 @@ def profile_compile_execute(wl, platform, policy: int = des.POLICY_FIFO,
             "compile_s": max(cold - execute, 0.0),
             "waves": int(tr.waves),
             "waves_per_s": tr.waves / max(execute, 1e-12)}
-
-
-def stage_attribution(wl, platform, scenario=None, fleet=None, probe=None,
-                      policy: int = des.POLICY_FIFO,
-                      repeats: int = 3) -> Dict[str, Dict[str, float]]:
-    """Per-stage wall attribution by differential ablation.
-
-    Returns ``{stage: {per_wave_us, waves, wall_s}}`` for the always-on
-    core (``select+completion+admission`` — the base config's whole wave)
-    and a delta entry per optional stage that was supplied (``control`` /
-    ``fleet`` / ``probe`` — that stage's config minus the base, per wave;
-    clipped at 0 when the delta drowns in noise). Stages the caller didn't
-    supply (no scenario/fleet/probe) are omitted, not estimated."""
-    configs = {"base": {}}
-    if scenario is not None:
-        configs["control"] = {"scenario": scenario}
-    if fleet is not None:
-        configs["fleet"] = {"fleet": fleet}
-    if probe is not None:
-        configs["probe"] = {"probe": probe}
-
-    measured = {}
-    for name, kw in configs.items():
-        prof = profile_compile_execute(wl, platform, policy, repeats=repeats,
-                                       **kw)
-        measured[name] = {"wall_s": prof["execute_s"],
-                          "waves": prof["waves"],
-                          "per_wave_us": 1e6 * prof["execute_s"]
-                          / max(prof["waves"], 1)}
-    base_pw = measured["base"]["per_wave_us"]
-    out = {"select+completion+admission": {
-        "per_wave_us": base_pw,
-        "waves": measured["base"]["waves"],
-        "wall_s": measured["base"]["wall_s"],
-    }}
-    for name in ("control", "fleet", "probe"):
-        if name not in measured:
-            continue
-        m = measured[name]
-        out[name] = {"per_wave_us": max(m["per_wave_us"] - base_pw, 0.0),
-                     "waves": m["waves"], "wall_s": m["wall_s"]}
-    return out
