@@ -167,9 +167,11 @@ def compare_fields(a, b, names) -> tuple:
 def compare_traces(a: M.SimTrace, b: M.SimTrace) -> tuple:
     """Every SimTrace buffer: schedules, attempts, controller, reliability,
     fleet and probe buffers, and the wave count. Not the batched engine's
-    ``ops_waves``, a diagnostic the other engines do not keep."""
+    ``ops_waves`` and ``fleet_waves``, diagnostics the other engines do not
+    keep (and ``fleet_waves`` depends on the rows batched together)."""
     return compare_fields(a, b, [f.name for f in dataclasses.fields(a)
-                                 if f.name != "ops_waves"])
+                                 if f.name not in ("ops_waves",
+                                                   "fleet_waves")])
 
 
 def sorted_records(rec):

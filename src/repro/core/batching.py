@@ -331,5 +331,7 @@ def batch_trace(out: dict, idx: int, wl: M.Workload,
         ctrl_caps=ctrl_caps,
         waves=int(out["waves"][idx]) if "waves" in out else None,
         ops_waves=int(out["ops_waves"][idx]) if "ops_waves" in out else None,
+        fleet_waves=int(out["fleet_waves"][idx]) if "fleet_waves" in out
+        else None,
         **fl_cols,
     )

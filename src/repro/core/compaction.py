@@ -337,6 +337,8 @@ def simulate_ensemble_compacted(
                waves=st["wave"])
     if "ops_waves" in st:
         res["ops_waves"] = st["ops_waves"]
+    if "fleet_waves" in st:
+        res["fleet_waves"] = st["fleet_waves"]
     if statics["n_attempt_slots"] is not None:
         res["att_start"] = st["att_start"]
         res["att_finish"] = st["att_finish"]

@@ -258,6 +258,13 @@ class SimTrace:
     # stage. A diagnostic of how the loop spent its waves, not a result: no
     # summary, record or parity check reads it. None otherwise
     ops_waves: Optional[int] = None
+    # loop iterations in which the fleet stage ran, counted by the JAX
+    # engine in a run with a fleet: the stage runs only where some row of
+    # the batch has a drift tick or a retraining pipeline that finished in
+    # that wave, so every row still running counts the same iterations. A
+    # diagnostic like ops_waves, and one that depends on the rows batched
+    # together; None otherwise
+    fleet_waves: Optional[int] = None
 
     def action_timeline(self):
         """The SHARED in-engine action timeline: every discrete action an

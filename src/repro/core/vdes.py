@@ -38,7 +38,13 @@ Each loop iteration (a **wave**) is composed of up to six named kernel stages:
      retraining pool (compile-time injection budget), and trigger/redeploy
      actions append to the shared action timeline. All randomness
      (observation noise, sudden-drift increments, redeploy gains, retrain
-     durations) is presampled outside the jitted loop;
+     durations) is presampled outside the jitted loop. The stage runs only
+     in loop iterations where some replica needs it (a drift tick, or a
+     retraining pipeline that finished in that wave), behind a conditional
+     whose predicate an ensemble reduces over its replicas, so that it
+     stays a scalar under ``vmap``. In any other wave the stage would be
+     the identity, so skipping it changes no bit; ``fleet_waves`` counts
+     the iterations in which it ran;
   6. **probe** (``_probe_stage``, optional): *in-loop telemetry*. At
      compile-time probe ticks (the same f32 tick-grid machinery again) the
      settled post-wave state — per-resource queue depth, busy slots,
@@ -121,6 +127,12 @@ INF = jnp.float32(CTRL_INF)   # the ONE shared f32 "never" sentinel
 _NOT_ARRIVED, _QUEUED, _RUNNING, _DONE = 0, 1, 2, 3
 
 _NO_RETRY_BACKOFF = (0.0, 2.0, 3600.0)
+
+# the carry the fleet stage writes (it reads ``phase`` besides)
+_FLEET_CARRY = ("fl_perf0", "fl_dep", "fl_acc", "fl_dep_tick",
+                "fl_fire", "t_fleet", "f_tick", "pool_model", "pool_next",
+                "pool_arr", "redeployed", "fleet_perf", "fleet_stale",
+                "fleet_act", "fleet_n", "fleet_waves")
 
 
 @jax.tree_util.register_pytree_node_class
@@ -325,7 +337,7 @@ def admission_mask_select(res_q: jnp.ndarray, pkey: jnp.ndarray,
 @partial(jax.jit,
          static_argnames=("policy", "n_attempt_slots", "admission_sort",
                           "n_ctrl_slots", "n_probe_slots", "n_rel_slots",
-                          "return_state"))
+                          "return_state", "batch_axis"))
 def simulate(vwl: VWorkload, capacities: jnp.ndarray, policy: int = POLICY_FIFO,
              cap_times: Optional[jnp.ndarray] = None,
              cap_vals: Optional[jnp.ndarray] = None,
@@ -343,7 +355,7 @@ def simulate(vwl: VWorkload, capacities: jnp.ndarray, policy: int = POLICY_FIFO,
              rel_times=None, rel_deltas=None,
              n_rel_slots: Optional[int] = None,
              resume=None, wave_budget=None, time_budget=None,
-             return_state: bool = False):
+             return_state: bool = False, batch_axis: Optional[str] = None):
     """Run one replica. Returns dict with start/finish/ready [N, T] (f32;
     NaN where a task does not exist or never ran) and the wave count.
 
@@ -434,6 +446,15 @@ def simulate(vwl: VWorkload, capacities: jnp.ndarray, policy: int = POLICY_FIFO,
     ``running``, and the count of still-live non-padding pipelines as
     ``n_keep``. Stopping at a wave boundary and resuming from the carry is
     bit-exact: the carry *is* the loop's complete state.
+
+    ``batch_axis`` (static) is set by :func:`simulate_ensemble` alone, to
+    the name of its ``vmap`` axis, and is no option of any caller above
+    it. The fleet stage runs behind a conditional whose predicate is the
+    wave's need for it (a drift tick, or a retraining pipeline that just
+    finished); reduced over that axis, the predicate stays a scalar, so
+    the batched loop keeps a real conditional instead of a ``select`` that
+    runs the stage in every wave. The carry counts the waves in which the
+    stage ran as ``fleet_waves``.
     """
     n, T = vwl.task_res.shape
     if (cap_times is None) != (cap_vals is None):
@@ -564,6 +585,7 @@ def simulate(vwl: VWorkload, capacities: jnp.ndarray, policy: int = POLICY_FIFO,
         # lifecycle action buffer: [A, 3] rows of (f32 time, kind, model id)
         state["fleet_act"] = jnp.full((A_f, 3), jnp.nan, jnp.float32)
         state["fleet_n"] = jnp.int32(0)
+        state["fleet_waves"] = jnp.int32(0)   # waves the stage ran in
     if has_probe:
         state["t_probe"] = jnp.where(p_enabled & (p_first <= p_end),
                                      p_first, INF)
@@ -608,7 +630,8 @@ def simulate(vwl: VWorkload, capacities: jnp.ndarray, policy: int = POLICY_FIFO,
     def _completion_stage(s, t_star):
         """Stage 2: finishes release slots; failed attempts re-enter the
         arrival path after their backoff delay; successful ones advance the
-        pipeline; arrivals and successor tasks enqueue."""
+        pipeline; arrivals and successor tasks enqueue. Also returns the
+        ``[N]`` mask of pipelines that finished in this wave."""
         s = dict(s)
         phase, task_idx, t_next = s["phase"], s["task_idx"], s["t_next"]
         finishing = (phase == _RUNNING) & (t_next == t_star)
@@ -649,7 +672,7 @@ def simulate(vwl: VWorkload, capacities: jnp.ndarray, policy: int = POLICY_FIFO,
         tcl = jnp.clip(task_idx, 0, T - 1)
         s["ready"] = jnp.where(_onehot_cols(tcl, T) & to_queue[:, None],
                                t_star, s["ready"])
-        return s
+        return s, done_now
 
     def _control_stage(s, t_star, t_cap):
         """Stage 3: the pending scheduled capacity change applies, then the
@@ -850,10 +873,11 @@ def simulate(vwl: VWorkload, capacities: jnp.ndarray, policy: int = POLICY_FIFO,
         state resets, presampled per-slot performance gain applies); at
         every drift-evaluation tick the [M] drift algebra runs, the
         performance/staleness timelines record, and triggers whose observed
-        drift crosses the threshold (outside their cooldown) activate
-        latent pool pipelines. Trigger and redeploy actions append to the
-        shared lifecycle action buffer. Arithmetic is float32 — the numpy
-        engine mirrors this stage operation-for-operation."""
+        drift crosses the threshold (outside their cooldown) take latent
+        pool slots (:func:`_fleet_gate` activates their pipelines). Trigger
+        and redeploy actions append to the shared lifecycle action buffer.
+        Arithmetic is float32 — the numpy engine mirrors this stage
+        operation-for-operation."""
         s = dict(s)
         slots = jnp.arange(P, dtype=jnp.int32)
         valid = slots < peff
@@ -923,10 +947,8 @@ def simulate(vwl: VWorkload, capacities: jnp.ndarray, policy: int = POLICY_FIFO,
             hit_s, jnp.max(jnp.where(m_s, mids[:, None], -1), axis=0),
             s["pool_model"])
         s["pool_arr"] = jnp.where(hit_s, arr_t, s["pool_arr"])
-        # activate the latent workload rows: they arrive at t_star + delay
-        row_idx = jnp.where(fire, pbase + slot, n)
-        hit_r = jnp.any(row_idx[:, None] == ids[None, :], axis=0)
-        s["t_next"] = jnp.where(hit_r, arr_t, s["t_next"])
+        # the latent workload rows of the fired slots arrive at
+        # t_star + delay: _fleet_gate activates them
         aidx = jnp.where(fire, s["fleet_n"] + rank, A_f)
         avals = jnp.stack(
             [jnp.full((M_,), t_star),
@@ -945,6 +967,29 @@ def simulate(vwl: VWorkload, capacities: jnp.ndarray, policy: int = POLICY_FIFO,
             s["t_fleet"])
         s["f_tick"] = s["f_tick"] + firing.astype(jnp.int32)
         return s
+
+    def _fleet_gate(s, t_star, need):
+        """Stage 5 only where the scalar ``need`` holds, counting those
+        waves in ``fleet_waves``. The conditional is a ``while_loop`` that
+        runs once or not at all: XLA updates its carry in place, where a
+        ``lax.cond``'s operands and results each take a buffer of their own
+        (compiled for a TPU v5e at the ops benchmark cell's shapes: 1.7 MB
+        more device memory than an ungated stage, against 0.2 MB). Its carry
+        is the fleet state alone: the ``[N]`` ``t_next`` write stays
+        outside, since the slots the triggers fired are consecutive from
+        ``pool_next``, and their latent rows the range between
+        ``pool_next`` before and after."""
+        def run(c):
+            o = _fleet_stage(dict(c[1], phase=s["phase"]), t_star)
+            o["fleet_waves"] = o["fleet_waves"] + 1
+            return jnp.zeros_like(need), {k: o[k] for k in _FLEET_CARRY}
+
+        _, o = jax.lax.while_loop(lambda c: c[0], run,
+                                  (need, {k: s[k] for k in _FLEET_CARRY}))
+        fired = ((ids >= pbase + s["pool_next"])
+                 & (ids < pbase + o["pool_next"]))
+        t_next = jnp.where(fired, t_star + f_delay, s["t_next"])
+        return {**s, **o, "t_next": t_next}
 
     def _probe_stage(s, t_star):
         """Stage 6 (optional): in-loop telemetry. Runs LAST in the wave so
@@ -1043,10 +1088,7 @@ def simulate(vwl: VWorkload, capacities: jnp.ndarray, policy: int = POLICY_FIFO,
             alive = alive | (s["t_probe"] < INF)
         return alive & (t_star < INF)
 
-    def cond(s):
-        with jax.named_scope("select"):
-            t_star = _select_events(s)[0]
-            go = _running(s, t_star)
+    def _within_budgets(s, t_star, go):
         if wave_budget is not None:
             # segment cap: stop at the budget boundary — a wave boundary is
             # a consistent cut, so the compaction driver resumes bit-exactly
@@ -1061,21 +1103,47 @@ def simulate(vwl: VWorkload, capacities: jnp.ndarray, policy: int = POLICY_FIFO,
             go = go & (t_star <= jnp.asarray(time_budget, jnp.float32))
         return go
 
+    def cond(s):
+        with jax.named_scope("select"):
+            t_star = _select_events(s)[0]
+            go = _running(s, t_star)
+        return _within_budgets(s, t_star, go)
+
     def body(s):
         # each stage runs under a named scope: the op names of the
         # compiled program (and so of a profiler trace) carry the stage,
         # which splits a wave's device time by stage; no op changes
         with jax.named_scope("select"):
             t_star, t_cap, t_ops = _select_events(s)
+        gate_fleet = has_fleet and batch_axis is not None
+        if gate_fleet:
+            with jax.named_scope("fleet"):
+                # this row's own loop condition: under vmap every row runs
+                # the body until the last row's loop ends, and a row whose
+                # loop has ended (where t_fleet == t_star == INF may hold)
+                # must not hold the fleet gate open
+                live = _within_budgets(s, t_star, _running(s, t_star))
         with jax.named_scope("completion"):
-            s = _completion_stage(s, t_star)
+            s, done_now = _completion_stage(s, t_star)
         with jax.named_scope("control"):
             s = _control_stage(s, t_star, t_cap)
         with jax.named_scope("admission"):
             s = _admission_stage(s, t_star)
         if has_fleet:
             with jax.named_scope("fleet"):
-                s = _fleet_stage(s, t_star)
+                # the stage has work only at a drift tick or when a
+                # retraining-pool pipeline finished in this wave; in every
+                # other wave its own masks make it the identity
+                pool = (ids >= pbase) & (ids < pbase + peff)
+                need = ((f_enabled & (s["t_fleet"] == t_star))
+                        | jnp.any(done_now & pool))
+                if gate_fleet:
+                    # one scalar for the batch keeps the conditional a
+                    # conditional under vmap (a batched predicate turns it
+                    # into a select that runs the stage in every wave)
+                    need = jax.lax.pmax((need & live).astype(jnp.int32),
+                                        batch_axis) > 0
+                s = _fleet_gate(s, t_star, need)
         if has_probe:
             with jax.named_scope("probe"):
                 s = _probe_stage(s, t_star)
@@ -1092,6 +1160,8 @@ def simulate(vwl: VWorkload, capacities: jnp.ndarray, policy: int = POLICY_FIFO,
                waves=out["wave"])
     if count_ops:
         res["ops_waves"] = out["ops_waves"]
+    if has_fleet:
+        res["fleet_waves"] = out["fleet_waves"]
     if n_attempt_slots is not None:
         res["att_start"] = out["att_start"]
         res["att_finish"] = out["att_finish"]
@@ -1242,6 +1312,7 @@ def simulate_to_trace(wl: M.Workload, platform: Optional[M.PlatformConfig] = Non
         ctrl_times=ctrl_times,
         ctrl_caps=ctrl_caps,
         waves=int(res["waves"]),
+        fleet_waves=int(res["fleet_waves"]) if fl is not None else None,
         **fl_cols,
     )
 
@@ -1249,6 +1320,9 @@ def simulate_to_trace(wl: M.Workload, platform: Optional[M.PlatformConfig] = Non
 # ---------------------------------------------------------------------------
 # Monte-Carlo ensembles: vmap over a replica axis. Tensors must share shapes.
 # ---------------------------------------------------------------------------
+
+_ROWS = "rows"   # the name of the ensemble's vmap axis
+
 
 @partial(jax.jit,
          static_argnames=("policy", "n_attempt_slots", "admission_sort",
@@ -1391,6 +1465,6 @@ def simulate_ensemble(arrival, n_tasks, task_res, service, priority,
                         resume=m.get("resume"),
                         wave_budget=m.get("wave_budget"),
                         time_budget=m.get("time_budget"),
-                        return_state=return_state)
+                        return_state=return_state, batch_axis=_ROWS)
 
-    return jax.vmap(one)(mapped)
+    return jax.vmap(one, axis_name=_ROWS)(mapped)

@@ -447,5 +447,11 @@ def test_clean_tree_jaxpr_audit_is_clean():
     active, suppressed = F.split_suppressed(fs, REPO_ROOT)
     assert active == [], [f.render() for f in active]
     # the one surviving loop reduction is the pragma'd redeploy-gain
-    # segment_sum (numpy mirrors its slot order; see vdes._fleet_stage)
+    # segment_sum (numpy mirrors its slot order; see vdes._fleet_stage).
+    # The fleet stage runs behind its gate, a control-flow op nested in
+    # the wave loop: the walk still reaches it and audits it as in-loop,
+    # in the single-replica and in the batched call
     assert {f.rule for f in suppressed} <= {"loop-reduce"}
+    gains = [f for f in suppressed if f.file.endswith("core/vdes.py")
+             and "segment_sum" in f.snippet]
+    assert len(gains) >= 2, [f.render() for f in suppressed]

@@ -94,22 +94,135 @@ def assert_traces_match(t_np, t_jx, wl):
 
 # ------------------------------------------------ engine-level parity
 
-def test_fleet_stage_wave_parity(rng):
-    """Numpy and JAX engines agree wave-for-wave with the feedback stage
-    enabled: same schedules, same drift timelines, same trigger/redeploy
-    actions — including presampled observation noise and sudden drift."""
-    wl = int_workload(rng)
-    plat = platform()
+def fleet_need_waves(tr):
+    """The waves at which the fleet stage has work in a numpy trace: drift
+    ticks the run reached and redeploys (a retraining pipeline finished),
+    counted once where both fall in one wave."""
+    reached = ~np.isnan(tr.fleet_perf).all(axis=1)
+    redeploys = tr.fleet_times[tr.fleet_kind == des.FLEET_ACT_REDEPLOY]
+    return len(set(tr.fleet_ticks[reached].tolist())
+               | set(redeploys.tolist()))
+
+
+def _ensemble_rows(rng, plat):
+    """Three fleet rows of one width whose drift ticks and redeploys fall
+    at different waves: tick intervals 20, 35 and 15 s, and the third row's
+    traffic and horizon end at 120 s, so its loop ends long before the
+    others'."""
     fl_t = fleet_params([0.9, 0.8, 0.95, 0.7], [2e-3, 1e-3, 5e-4, 3e-3],
                         jump_rate=[0.01, 0.02, 0.0, 0.005],
                         jump_scale=[0.05, 0.02, 0.0, 0.1])
-    cf, ext = compile_fleet(FleetSpec(params=fl_t), TRIG, wl, plat, 300.0,
-                            seed=3)
-    t_np = des.simulate(ext, plat, scenario=None, fleet=cf)
-    t_jx = vdes.simulate_to_trace(ext, plat, fleet=cf)
-    assert_traces_match(t_np, t_jx, ext)
-    assert (t_np.fleet_kind == des.FLEET_ACT_TRIGGER).sum() >= 2
-    assert (t_np.fleet_kind == des.FLEET_ACT_REDEPLOY).sum() >= 1
+    rows = []
+    for interval, horizon, seed in ((20.0, 300.0, 3), (35.0, 300.0, 4),
+                                    (15.0, 120.0, 5)):
+        trig = dataclasses.replace(TRIG, interval_s=interval, max_retrains=6)
+        wl = int_workload(rng, horizon=horizon)
+        rows.append(compile_fleet(FleetSpec(params=fl_t), trig, wl, plat,
+                                  horizon, seed=seed))
+    return rows
+
+
+@pytest.mark.parametrize("rows", [1, 3], ids=["single", "ensemble3"])
+def test_fleet_stage_wave_parity(rng, rows):
+    """Numpy and JAX engines agree wave-for-wave with the feedback stage
+    enabled: same schedules, same drift timelines, same trigger/redeploy
+    actions — including presampled observation noise and sudden drift.
+    The JAX engine runs the stage only in waves with a drift tick or a
+    finished retrain (``fleet_waves`` counts them). In a batch the stage
+    runs where any still-running row needs it, so a row's count lies
+    between its own need and the batch's summed need; rows whose loop has
+    ended hold the gate shut."""
+    plat = platform()
+    if rows == 1:
+        wl = int_workload(rng)
+        fl_t = fleet_params([0.9, 0.8, 0.95, 0.7], [2e-3, 1e-3, 5e-4, 3e-3],
+                            jump_rate=[0.01, 0.02, 0.0, 0.005],
+                            jump_scale=[0.05, 0.02, 0.0, 0.1])
+        cf, ext = compile_fleet(FleetSpec(params=fl_t), TRIG, wl, plat,
+                                300.0, seed=3)
+        t_np = des.simulate(ext, plat, scenario=None, fleet=cf)
+        t_jx = vdes.simulate_to_trace(ext, plat, fleet=cf)
+        assert_traces_match(t_np, t_jx, ext)
+        assert (t_np.fleet_kind == des.FLEET_ACT_TRIGGER).sum() >= 2
+        assert (t_np.fleet_kind == des.FLEET_ACT_REDEPLOY).sum() >= 1
+        assert t_jx.fleet_waves == fleet_need_waves(t_np)
+        return
+    from repro.core import batching
+    pairs = _ensemble_rows(rng, plat)
+    exts = [ext for _, ext in pairs]
+    cols = batching.pad_workloads(exts, plat)
+    assert all(ext.n == cols["n_max"] for ext in exts)   # no padding rows
+    out = vdes.simulate_ensemble(
+        *(cols[k] for k in ("arrival", "n_tasks", "task_res", "service",
+                            "priority")),
+        np.tile(np.asarray(plat.capacities, np.int32), (rows, 1)),
+        **batching.stack_fleets([cf for cf, _ in pairs], cols["n_max"]))
+    out = {k: np.asarray(v) for k, v in out.items()}
+    t_nps = [des.simulate(ext, plat, scenario=None, fleet=cf)
+             for cf, ext in pairs]
+    t_jxs = [batching.batch_trace(out, i, ext, plat.capacities,
+                                  with_scenario=False, fleet=cf)
+             for i, (cf, ext) in enumerate(pairs)]
+    for (cf, ext), t_np, t_jx in zip(pairs, t_nps, t_jxs):
+        assert_traces_match(t_np, t_jx, ext)
+        assert (t_np.fleet_kind == des.FLEET_ACT_REDEPLOY).sum() >= 1
+    waves = [t.waves for t in t_nps]
+    assert waves[2] < min(waves[:2])          # one row ends early
+    redeploys = [set(t.fleet_times[t.fleet_kind == des.FLEET_ACT_REDEPLOY])
+                 for t in t_nps]
+    assert redeploys[0] != redeploys[1]
+    need = [fleet_need_waves(t) for t in t_nps]
+    counts = [t.fleet_waves for t in t_jxs]
+    for own, got in zip(need, counts):
+        assert own <= got <= sum(need)
+    assert max(need) <= max(counts) <= sum(need)
+    # the rows' needs overlap only in part: the gate ran in more waves
+    # than any one row needed
+    assert max(counts) > max(need)
+
+
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs nested in it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _eqns(inner)
+
+
+@pytest.mark.parametrize("fleet", [True, False], ids=["fleet", "no_fleet"])
+def test_fleet_gate_is_a_conditional_under_vmap(rng, fleet):
+    """Under ``simulate_ensemble``'s vmap the fleet stage sits behind a
+    control-flow op with an unbatched (scalar) predicate, reduced over the
+    rows with ``pmax``. A batched predicate would turn it into a select
+    that runs the stage in every wave: still exact, but as slow as no gate.
+    Without a fleet the wave loop holds no gate, no ``cond`` and no
+    ``pmax``."""
+    import jax
+    from repro.core import batching
+    plat = platform()
+    pairs = _ensemble_rows(rng, plat)
+    exts = [ext for _, ext in pairs]
+    cols = batching.pad_workloads(exts, plat)
+    kw = (batching.stack_fleets([cf for cf, _ in pairs], cols["n_max"])
+          if fleet else {})
+    closed = jax.make_jaxpr(lambda *a: vdes.simulate_ensemble(*a, **kw))(
+        *(cols[k] for k in ("arrival", "n_tasks", "task_res", "service",
+                            "priority")),
+        np.tile(np.asarray(plat.capacities, np.int32), (len(pairs), 1)))
+    loop = next(e for e in _eqns(closed.jaxpr) if e.primitive.name == "while")
+    body = list(_eqns(loop.params["body_jaxpr"].jaxpr))
+    names = [e.primitive.name for e in body]
+    scalar_gates = [e for e in body if e.primitive.name == "while"
+                    and e.params["cond_jaxpr"].out_avals[0].shape == ()]
+    assert "cond" not in names
+    if fleet:
+        assert len(scalar_gates) == 1
+        assert names.count("pmax") == 1
+    else:
+        assert scalar_gates == [] and "pmax" not in names
 
 
 def test_fleet_stage_parity_under_failure_scenario(rng):
