@@ -164,14 +164,18 @@ def compare_fields(a, b, names) -> tuple:
     return drift, bad
 
 
+_DIAGNOSTICS = ("ops_waves", "fleet_waves", "retries", "attempt_slots",
+                "rel_slot_s")
+
+
 def compare_traces(a: M.SimTrace, b: M.SimTrace) -> tuple:
     """Every SimTrace buffer: schedules, attempts, controller, reliability,
     fleet and probe buffers, and the wave count. Not the batched engine's
-    ``ops_waves`` and ``fleet_waves``, diagnostics the other engines do not
-    keep (and ``fleet_waves`` depends on the rows batched together)."""
+    diagnostics, which the other engines do not keep: ``ops_waves``,
+    ``fleet_waves`` (it depends on the rows batched together) and the
+    failure and recovery counters of ``batching.batch_trace``."""
     return compare_fields(a, b, [f.name for f in dataclasses.fields(a)
-                                 if f.name not in ("ops_waves",
-                                                   "fleet_waves")])
+                                 if f.name not in _DIAGNOSTICS])
 
 
 def sorted_records(rec):

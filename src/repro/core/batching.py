@@ -156,10 +156,20 @@ def stack_scenarios(compiled, n_max: int, horizon_s: float,
     # largest requested attempt count (and every resampled slot), so
     # accounting stays exact. With no retries anywhere the single-attempt
     # records already are exact — skip the extra [B, N, T, A] buffers.
-    slots = int(max(int(out["attempts"].max()), A))
+    slots = max(attempt_width(c) for c in compiled)
     if record_attempts and slots > 1:
         out["n_attempt_slots"] = slots
     return out
+
+
+def attempt_width(compiled) -> int:
+    """The per-attempt width of one compiled scenario: its largest
+    requested attempt count or resampled slot count, at least 1. A
+    one-entry run records its per-attempt buffers at this width, and none
+    where it is 1."""
+    asv = getattr(compiled, "attempt_service", None)
+    return int(max(np.max(compiled.attempts, initial=1),
+                   asv.shape[2] if asv is not None else 1))
 
 
 def stack_fleets(fleets, n_max: int) -> dict:
@@ -275,7 +285,8 @@ def stack_reliability(rels) -> dict:
 def batch_trace(out: dict, idx: int, wl: M.Workload,
                 capacities: np.ndarray,
                 with_scenario: bool = True, fleet=None,
-                probe=None, reliability=None) -> M.SimTrace:
+                probe=None, reliability=None,
+                n_attempts: Optional[int] = None) -> M.SimTrace:
     """Slice entry ``idx`` of a ``simulate_ensemble`` result back into a
     numpy :class:`SimTrace` for ``wl`` (dropping padded pipelines). With
     ``with_scenario=False`` the attempt/completion columns are omitted so
@@ -286,9 +297,27 @@ def batch_trace(out: dict, idx: int, wl: M.Workload,
     :class:`~repro.obs.probes.CompiledProbe`) likewise slices the probe
     buffer to the entry's own tick grid; ``reliability`` (the entry's
     :class:`~repro.reliability.compile.CompiledReliability`) decodes the
-    fired-event buffer back into ``rel_times``/``rel_caps``."""
+    fired-event buffer back into ``rel_times``/``rel_caps``. ``n_attempts``
+    (the entry's own per-attempt width: its largest requested attempt
+    count or resampled slot count) slices the per-attempt buffers, which
+    the batch pads to its widest entry, back to the entry's own columns,
+    and leaves them out where it is 1, as a one-entry run reports them;
+    None keeps the batch's width.
+
+    The trace also carries the row's failure and recovery counters
+    (``retries``, ``attempt_slots``, ``rel_slot_s``; see
+    :class:`~repro.core.model.SimTrace`)."""
     n = wl.n
     sl = lambda k: np.asarray(out[k][idx][:n], np.float64)
+    att_start = att_finish = None
+    if with_scenario and "att_start" in out \
+            and (n_attempts is None or n_attempts > 1):
+        att_start, att_finish = sl("att_start"), sl("att_finish")
+        if n_attempts is not None:
+            att_start = att_start[..., :n_attempts]
+            att_finish = att_finish[..., :n_attempts]
+    attempts = (np.asarray(out["attempts"][idx][:n], np.int64)
+                if with_scenario else None)
     ctrl_times = ctrl_caps = None
     if with_scenario and "ctrl_act" in out:
         from repro.core.des import unpack_ctrl_actions
@@ -314,24 +343,34 @@ def batch_trace(out: dict, idx: int, wl: M.Workload,
         from repro.core.des import unpack_rel_actions
         rt, rc = unpack_rel_actions(out["rel_act"][idx], out["rel_n"][idx])
         fl_cols.update(rel_times=rt, rel_caps=rc)
+    rel_slot_s = None
+    if reliability is not None:
+        rel_slot_s = 0.0
+        if "rel_times" in fl_cols:
+            from repro.ops.accounting import down_node_seconds
+            rel_slot_s = float(down_node_seconds(
+                fl_cols["rel_times"], fl_cols["rel_caps"],
+                reliability.horizon_s).sum())
     return M.SimTrace(
         start=sl("start"), finish=sl("finish"), ready=sl("ready"),
         n_tasks=wl.n_tasks.astype(np.int64), task_res=wl.task_res,
         task_type=wl.task_type, arrival=arrival,
         capacities=np.asarray(capacities, np.int64),
-        attempts=np.asarray(out["attempts"][idx][:n], np.int64)
-        if with_scenario else None,
+        attempts=attempts,
         completed=np.asarray(out["done"][idx][:n])
         if with_scenario or fleet is not None else None,
-        att_start=sl("att_start") if with_scenario and "att_start" in out
-        else None,
-        att_finish=sl("att_finish") if with_scenario and "att_finish" in out
-        else None,
+        att_start=att_start,
+        att_finish=att_finish,
         ctrl_times=ctrl_times,
         ctrl_caps=ctrl_caps,
         waves=int(out["waves"][idx]) if "waves" in out else None,
         ops_waves=int(out["ops_waves"][idx]) if "ops_waves" in out else None,
         fleet_waves=int(out["fleet_waves"][idx]) if "fleet_waves" in out
         else None,
+        retries=int(np.maximum(attempts - 1, 0).sum())
+        if attempts is not None else None,
+        attempt_slots=int(out["att_start"].shape[-1]) if "att_start" in out
+        else None,
+        rel_slot_s=rel_slot_s,
         **fl_cols,
     )

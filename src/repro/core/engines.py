@@ -23,6 +23,7 @@ results are :class:`repro.core.experiment.ExperimentResult` either way.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 import warnings
@@ -192,27 +193,33 @@ def _spec_workloads(spec, params, cache=None):
             fleets.append(cf)
             ext.append(w2)
         wls = ext
-    rels = None
-    if getattr(spec, "reliability", None) is not None:
-        from repro.reliability import (check_no_double_apply,
-                                       compile_reliability)
-        check_no_double_apply(spec.reliability, spec.scenario)
-        rels = [compile_reliability(spec.reliability, w, spec.platform,
-                                    spec.horizon_s,
-                                    seed=spec.seed + 1000 * r)
-                for r, w in enumerate(wls)]
-    compiled = None
-    if spec.scenario is not None:
-        compiled = [spec.scenario.compile(w, spec.platform, spec.horizon_s,
-                                          seed=spec.seed + 1000 * r,
-                                          policy=spec.policy)
+    # failure sampling and the reliability timeline run inside a span of
+    # their own, where the spec has either
+    faults = (getattr(spec, "reliability", None) is not None
+              or getattr(spec.scenario, "failures", None) is not None)
+    with profile.span("faults") if faults else contextlib.nullcontext():
+        rels = None
+        if getattr(spec, "reliability", None) is not None:
+            from repro.reliability import (check_no_double_apply,
+                                           compile_reliability)
+            check_no_double_apply(spec.reliability, spec.scenario)
+            rels = [compile_reliability(spec.reliability, w, spec.platform,
+                                        spec.horizon_s,
+                                        seed=spec.seed + 1000 * r)
                     for r, w in enumerate(wls)]
-    if rels is not None:
-        compiled = [_fold_reliability(
-            compiled[r] if compiled is not None else None, rels[r], w,
-            spec.platform) for r, w in enumerate(wls)]
-        if all(c is None for c in compiled):
-            compiled = None
+        compiled = None
+        if spec.scenario is not None:
+            compiled = [spec.scenario.compile(w, spec.platform,
+                                              spec.horizon_s,
+                                              seed=spec.seed + 1000 * r,
+                                              policy=spec.policy)
+                        for r, w in enumerate(wls)]
+        if rels is not None:
+            compiled = [_fold_reliability(
+                compiled[r] if compiled is not None else None, rels[r], w,
+                spec.platform) for r, w in enumerate(wls)]
+            if all(c is None for c in compiled):
+                compiled = None
     probe = None
     if getattr(spec, "probe", None) is not None:
         from repro.obs.probes import compile_probe
@@ -469,11 +476,12 @@ class JaxEngine:
                 last_tr = None
                 for r in range(spec.n_replicas):
                     _, wl, comp, fl, pr, rl = entries[i + r]
-                    tr = batching.batch_trace(out, i + r, wl,
-                                              spec.platform.capacities,
-                                              with_scenario=comp is not None,
-                                              fleet=fl, probe=pr,
-                                              reliability=rl)
+                    tr = batching.batch_trace(
+                        out, i + r, wl, spec.platform.capacities,
+                        with_scenario=comp is not None, fleet=fl, probe=pr,
+                        reliability=rl,
+                        n_attempts=batching.attempt_width(comp)
+                        if comp is not None else None)
                     last_tr = tr
                     rec = trace.flatten_trace(tr, wl)
                     recs.append(rec)

@@ -265,6 +265,17 @@ class SimTrace:
     # diagnostic like ops_waves, and one that depends on the rows batched
     # together; None otherwise
     fleet_waves: Optional[int] = None
+    # failure and recovery counters of a batched row (batching.batch_trace,
+    # from buffers the row already holds; diagnostics like ops_waves, read
+    # by no summary, record or parity check). retries: executed attempts
+    # beyond each task's first (None without a scenario); attempt_slots:
+    # the per-attempt width A the batched call compiled with (None without
+    # per-attempt records); rel_slot_s: slot-seconds that the fired
+    # reliability events held out of service, from the first event to the
+    # horizon (None without reliability)
+    retries: Optional[int] = None
+    attempt_slots: Optional[int] = None
+    rel_slot_s: Optional[float] = None
 
     def action_timeline(self):
         """The SHARED in-engine action timeline: every discrete action an

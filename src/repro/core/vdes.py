@@ -56,7 +56,11 @@ Each loop iteration (a **wave**) is composed of up to six named kernel stages:
 Each stage runs under a ``jax.named_scope`` of its name (``select``,
 ``completion``, ``control``, ``admission``, ``fleet``, ``probe``): the op
 names of the compiled program, and so of a profiler trace of it, carry the
-stage, which is how a trace splits a wave's device time by stage. In a
+stage, which is how a trace splits a wave's device time by stage. Three
+nested scopes name the failure and recovery work inside a stage:
+``completion/retry`` (the retry test and its backoff),
+``control/reliability`` (the reliability event) and ``admission/attempts``
+(the per-attempt record rewrite). In a
 program with a capacity schedule or an operations stage the carry also
 counts, per replica and from the event minimum the wave already computes,
 the waves at which an operations event was due (``ops_waves``): a capacity
@@ -651,9 +655,12 @@ def simulate(vwl: VWorkload, capacities: jnp.ndarray, policy: int = POLICY_FIFO,
         s["free"] = s["free"] + freed
 
         att = s["attempt"]
-        retrying = finishing & (att + 1 < _take_cols(att_req, oh0, 0))
+        with jax.named_scope("retry"):
+            # a finished attempt that must run again, and its backoff
+            retrying = finishing & (att + 1 < _take_cols(att_req, oh0, 0))
+            delay = jnp.minimum(bo[0] * bo[1] ** att.astype(jnp.float32),
+                                bo[2])
         succeeding = finishing & ~retrying
-        delay = jnp.minimum(bo[0] * bo[1] ** att.astype(jnp.float32), bo[2])
 
         task_idx = task_idx + succeeding.astype(jnp.int32)
         att = jnp.where(retrying, att + 1,
@@ -688,29 +695,31 @@ def simulate(vwl: VWorkload, capacities: jnp.ndarray, policy: int = POLICY_FIFO,
                                      0)
         cap_idx = s["cap_idx"] + cap_changing.astype(jnp.int32)
         if has_rel:
-            # reliability capacity-delta event: same drain semantics as a
-            # scheduled decrease, applied before the controller evaluates
-            # so it reacts to post-outage capacity (numpy mirrors)
-            ri = jnp.clip(s["rel_idx"], 0, RV - 1)
-            rel_firing = (s["rel_idx"] < RV) & (rel_t[ri] == t_star)
-            drow = jnp.where(rel_firing, rel_d[ri], 0)
-            free = free + drow
-            rel_cum = s["rel_cum"] + drow
-            # record (t, cumulative delta) with the controller buffer's
-            # dense one-hot row-write pattern (scatters serialize on CPU);
-            # cumulative deltas can be negative, so a where-write, not
-            # _onehot_rows
-            ridx = jnp.minimum(s["rel_n"], n_rel_slots - 1)
-            rrow = jnp.concatenate([jnp.reshape(t_star, (1,)),
-                                    rel_cum.astype(jnp.float32)])
-            oh_r = (jnp.arange(n_rel_slots, dtype=jnp.int32)
-                    == ridx)[:, None]
-            s["rel_act"] = jnp.where(oh_r & rel_firing, rrow[None, :],
-                                     s["rel_act"])
-            s["rel_n"] = jnp.minimum(
-                s["rel_n"] + rel_firing.astype(jnp.int32), n_rel_slots)
-            s["rel_cum"] = rel_cum
-            s["rel_idx"] = s["rel_idx"] + rel_firing.astype(jnp.int32)
+            with jax.named_scope("reliability"):
+                # reliability capacity-delta event: same drain semantics as
+                # a scheduled decrease, applied before the controller
+                # evaluates so it reacts to post-outage capacity (numpy
+                # mirrors)
+                ri = jnp.clip(s["rel_idx"], 0, RV - 1)
+                rel_firing = (s["rel_idx"] < RV) & (rel_t[ri] == t_star)
+                drow = jnp.where(rel_firing, rel_d[ri], 0)
+                free = free + drow
+                rel_cum = s["rel_cum"] + drow
+                # record (t, cumulative delta) with the controller buffer's
+                # dense one-hot row-write pattern (scatters serialize on
+                # CPU); cumulative deltas can be negative, so a where-write,
+                # not _onehot_rows
+                ridx = jnp.minimum(s["rel_n"], n_rel_slots - 1)
+                rrow = jnp.concatenate([jnp.reshape(t_star, (1,)),
+                                        rel_cum.astype(jnp.float32)])
+                oh_r = (jnp.arange(n_rel_slots, dtype=jnp.int32)
+                        == ridx)[:, None]
+                s["rel_act"] = jnp.where(oh_r & rel_firing, rrow[None, :],
+                                         s["rel_act"])
+                s["rel_n"] = jnp.minimum(
+                    s["rel_n"] + rel_firing.astype(jnp.int32), n_rel_slots)
+                s["rel_cum"] = rel_cum
+                s["rel_idx"] = s["rel_idx"] + rel_firing.astype(jnp.int32)
         if has_ctrl:
             firing = c_enabled & (s["t_eval"] == t_star)
             queued = s["phase"] == _QUEUED
@@ -858,13 +867,16 @@ def simulate(vwl: VWorkload, capacities: jnp.ndarray, policy: int = POLICY_FIFO,
                         axis=0, dtype=jnp.int32)
         s["free"] = s["free"] - taken
         if n_attempt_slots is not None:
-            ka = jnp.clip(att, 0, n_attempt_slots - 1)
-            adm_slot = adm_col[:, :, None] & (
-                ka[:, None, None]
-                == jnp.arange(n_attempt_slots, dtype=jnp.int32)[None, None, :])
-            s["att_start"] = jnp.where(adm_slot, t_star, s["att_start"])
-            s["att_finish"] = jnp.where(adm_slot, t_fin[:, None, None],
-                                        s["att_finish"])
+            with jax.named_scope("attempts"):
+                # per-attempt records: the whole [N, T, A] buffers rewritten
+                ka = jnp.clip(att, 0, n_attempt_slots - 1)
+                adm_slot = adm_col[:, :, None] & (
+                    ka[:, None, None]
+                    == jnp.arange(n_attempt_slots,
+                                  dtype=jnp.int32)[None, None, :])
+                s["att_start"] = jnp.where(adm_slot, t_star, s["att_start"])
+                s["att_finish"] = jnp.where(adm_slot, t_fin[:, None, None],
+                                            s["att_finish"])
         return s
 
     def _fleet_stage(s, t_star):
@@ -1241,8 +1253,8 @@ def simulate_to_trace(wl: M.Workload, platform: Optional[M.PlatformConfig] = Non
         att_svc = getattr(scenario, "attempt_service", None)
         ctrl = getattr(scenario, "controller", None)
         frac = float(getattr(scenario, "fail_holds_frac", 1.0))
-        slots = int(max(np.max(scenario.attempts), 1,
-                        att_svc.shape[2] if att_svc is not None else 1))
+        from repro.core.batching import attempt_width
+        slots = attempt_width(scenario)
         if slots == 1:   # no retries: single-attempt records already exact
             slots = None
         n_ctrl = ctrl_tick_bound(ctrl) if ctrl is not None else 0

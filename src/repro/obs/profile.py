@@ -6,7 +6,9 @@ modeled; this module instruments the model:
   - :func:`span` — the program's own host spans at the layer boundaries of
     a sweep (``Sweep.run``: ``sweep`` and the grid's ``points``;
     ``JaxEngine.run_sweep``: ``prep``, ``batching``, ``upload``,
-    ``engine``, ``fetch``, ``summaries``, ``results``). Each is a
+    ``engine``, ``fetch``, ``summaries``, ``results``; inside ``prep``,
+    ``engines._spec_workloads``'s ``faults`` around failure sampling and
+    the reliability timeline, where a spec has either). Each is a
     ``jax.profiler.TraceAnnotation`` named ``pipesim/<name>``, so under the
     profiler it lands in the trace on the device's clock, and a record
     ``(name, parent, sweep, start_ns, end_ns)`` on the

@@ -185,6 +185,16 @@ def lifecycle_summary(tr) -> Dict:
     }
 
 
+def down_node_seconds(times, cum_deltas, horizon_s: float) -> np.ndarray:
+    """``[R]`` ∫ nodes-down dt per resource of a reliability event
+    timeline: the cumulative delta after each event (``cum_deltas [E, R]``,
+    <= 0) held until the next event, the last until ``horizon_s``."""
+    ts = np.asarray(times, np.float64)
+    cum = np.asarray(cum_deltas, np.float64)
+    dt = np.diff(np.concatenate([ts, [horizon_s]])).clip(0.0, None)
+    return (np.maximum(-cum, 0.0) * dt[:, None]).sum(0)
+
+
 def availability_summary(rel, platform, tr=None) -> Dict:
     """The reliability block :func:`repro.core.engines._summarize` folds
     into each replica's summary (``summary["availability"]``).
@@ -211,10 +221,7 @@ def availability_summary(rel, platform, tr=None) -> Dict:
     # ∫ nodes-down dt per resource, truncated at the horizon
     down_node_s = np.zeros(nres)
     if rel.n_events:
-        ts = np.asarray(rel.times, np.float64)
-        cum = rel.cum_deltas().astype(np.float64)          # [RV, R], <= 0
-        dt = np.diff(np.concatenate([ts, [h]])).clip(0.0, None)
-        down_node_s = (np.maximum(-cum, 0.0) * dt[:, None]).sum(0)
+        down_node_s = down_node_seconds(rel.times, rel.cum_deltas(), h)
     denom = np.maximum(base * h, 1e-12)
     avail = 1.0 - down_node_s / denom
 
