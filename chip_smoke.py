@@ -165,7 +165,7 @@ def compare_fields(a, b, names) -> tuple:
 
 
 _DIAGNOSTICS = ("ops_waves", "fleet_waves", "retries", "attempt_slots",
-                "rel_slot_s")
+                "attempt_log_slots", "rel_slot_s")
 
 
 def compare_traces(a: M.SimTrace, b: M.SimTrace) -> tuple:

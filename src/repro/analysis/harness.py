@@ -28,7 +28,7 @@ from repro.core.metrics import FLEET_FIELDS
 from repro.core.runtime import FleetSpec, TriggerSpec
 
 #: static (hashable, compile-key) argnames of both vdes entry points
-STATIC_ARGNAMES = ("policy", "n_attempt_slots", "admission_sort",
+STATIC_ARGNAMES = ("policy", "n_retry_slots", "admission_sort",
                    "n_ctrl_slots", "n_probe_slots", "n_rel_slots",
                    "return_state")
 

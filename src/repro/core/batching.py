@@ -15,7 +15,8 @@ benchmarks) shares one tested implementation:
     no-op change points, attempts padded with 1, per-attempt service tensors
     padded to a common attempt-slot width);
   - :func:`batch_trace` — slice one entry's result back out as a
-    :class:`repro.core.model.SimTrace`.
+    :class:`repro.core.model.SimTrace`, its per-attempt arrays rebuilt
+    from the retry log by :func:`attempt_records`.
 """
 from __future__ import annotations
 
@@ -67,8 +68,9 @@ def stack_scenarios(compiled, n_max: int, horizon_s: float,
                     record_ctrl: bool = True) -> dict:
     """Pad/stack per-entry CompiledScenarios into the ``[B, ...]`` scenario
     kwargs of ``vdes.simulate_ensemble`` (``attempts`` / ``cap_times`` /
-    ``cap_vals`` / ``backoff``, plus ``attempt_service`` and the static
-    ``n_attempt_slots`` when any entry resamples retry durations,
+    ``cap_vals`` / ``backoff``, plus ``attempt_service`` when any entry
+    resamples retry durations, the static ``n_retry_slots`` when any entry
+    has a per-attempt width above 1 (see below),
     ``controllers [B, C]`` — plus the static ``n_ctrl_slots`` for
     realized-timeline recording, opt-out via ``record_ctrl=False`` — when
     any entry carries a closed-loop ControllerParams tensor, and
@@ -83,6 +85,13 @@ def stack_scenarios(compiled, n_max: int, horizon_s: float,
     without a controller get the all-zero disabled row; entries without
     partial-progress failures get fraction 1.0 — both exactly the
     no-scenario semantics.
+
+    Per-attempt recording (opt-out via ``record_attempts=False``) is on
+    where some entry's :func:`attempt_width` exceeds 1. The wave loop then
+    logs each pipeline's non-final attempts in ``n_retry_slots`` columns:
+    the batch's :func:`retry_width`, read from the attempts. Its records
+    are rebuilt at each entry's own width on the host
+    (:func:`batch_trace`).
     """
     K = max(c.cap_times.shape[0] for c in compiled)
     slot_widths = [c.attempt_service.shape[2] for c in compiled
@@ -151,14 +160,14 @@ def stack_scenarios(compiled, n_max: int, horizon_s: float,
                       for c in compiled], np.float32)
     if (fracs < 1.0).any():
         out["fail_holds_frac"] = fracs
-    # per-attempt recording slots (opt-out via record_attempts=False, e.g.
-    # for throughput benchmarks that never read them): enough for the
-    # largest requested attempt count (and every resampled slot), so
-    # accounting stays exact. With no retries anywhere the single-attempt
-    # records already are exact — skip the extra [B, N, T, A] buffers.
+    # per-attempt recording (opt-out via record_attempts=False, e.g. for
+    # throughput benchmarks that never read it): with no retries or
+    # resampled slots anywhere the single-attempt records already are
+    # exact. The loop logs only the attempts that are not their task's
+    # last, in as many columns as the most any pipeline needs
     slots = max(attempt_width(c) for c in compiled)
     if record_attempts and slots > 1:
-        out["n_attempt_slots"] = slots
+        out["n_retry_slots"] = max(retry_width(c.attempts) for c in compiled)
     return out
 
 
@@ -170,6 +179,45 @@ def attempt_width(compiled) -> int:
     asv = getattr(compiled, "attempt_service", None)
     return int(max(np.max(compiled.attempts, initial=1),
                    asv.shape[2] if asv is not None else 1))
+
+
+def retry_width(attempts) -> int:
+    """The retry-log width a set of pipelines needs: the most non-final
+    attempts any one requests, ``attempts - 1`` summed over its tasks
+    (``attempts [N, T]``; a padding task requests one attempt)."""
+    a = np.asarray(attempts, np.int64)
+    return int(np.maximum(a - 1, 0).sum(axis=1).max(initial=0))
+
+
+def attempt_records(start, finish, attempts, log_start, log_finish,
+                    width: int):
+    """``(att_start, att_finish) [N, T, width]``: the per-attempt records
+    of one row, placed from what the wave loop keeps. ``start``/``finish
+    [N, T]`` hold each task's last executed attempt, ``attempts [N, T]``
+    the attempts executed, and the retry log ``log_start``/``log_finish
+    [N, R]`` the earlier ones, task after task: attempt ``k`` of task
+    ``t`` at slot ``sum(attempts[:t] - 1) + k`` (every task before ``t``
+    ran to success, so executed equals requested there). NaN where no
+    attempt ran. No value is computed, only placed."""
+    att = np.asarray(attempts, np.int64)
+    n, T = att.shape
+    # a NaN column past the log: where nothing is read from it
+    logs = [np.concatenate([np.asarray(v, np.float64),
+                            np.full((n, 1), np.nan)], axis=1)
+            for v in (log_start, log_finish)]
+    R = logs[0].shape[1] - 1
+    k = np.arange(width)[None, None, :]
+    last = (att - 1)[..., None]
+    base = np.cumsum(np.maximum(att - 1, 0), axis=1) - np.maximum(att - 1, 0)
+    slot = np.where(k < last, base[..., None] + k, R)
+    out = []
+    for final, log in zip((start, finish), logs):
+        logged = np.take_along_axis(log, slot.reshape(n, -1),
+                                    axis=1).reshape(n, T, width)
+        out.append(np.where(k == last,
+                            np.asarray(final, np.float64)[..., None],
+                            logged))
+    return tuple(out)
 
 
 def stack_fleets(fleets, n_max: int) -> dict:
@@ -297,27 +345,31 @@ def batch_trace(out: dict, idx: int, wl: M.Workload,
     :class:`~repro.obs.probes.CompiledProbe`) likewise slices the probe
     buffer to the entry's own tick grid; ``reliability`` (the entry's
     :class:`~repro.reliability.compile.CompiledReliability`) decodes the
-    fired-event buffer back into ``rel_times``/``rel_caps``. ``n_attempts``
-    (the entry's own per-attempt width: its largest requested attempt
-    count or resampled slot count) slices the per-attempt buffers, which
-    the batch pads to its widest entry, back to the entry's own columns,
-    and leaves them out where it is 1, as a one-entry run reports them;
-    None keeps the batch's width.
+    fired-event buffer back into ``rel_times``/``rel_caps``. Where the
+    call recorded attempts (its result holds the retry log), the
+    per-attempt arrays are rebuilt (:func:`attempt_records`) at
+    ``n_attempts``, the entry's own per-attempt width (its largest
+    requested attempt count or resampled slot count), and left out where
+    it is 1, as a one-entry run reports them; None takes the most attempts
+    the row executed.
 
     The trace also carries the row's failure and recovery counters
-    (``retries``, ``attempt_slots``, ``rel_slot_s``; see
-    :class:`~repro.core.model.SimTrace`)."""
+    (``retries``, ``attempt_slots``, ``attempt_log_slots``, ``rel_slot_s``;
+    see :class:`~repro.core.model.SimTrace`)."""
     n = wl.n
     sl = lambda k: np.asarray(out[k][idx][:n], np.float64)
-    att_start = att_finish = None
-    if with_scenario and "att_start" in out \
-            and (n_attempts is None or n_attempts > 1):
-        att_start, att_finish = sl("att_start"), sl("att_finish")
-        if n_attempts is not None:
-            att_start = att_start[..., :n_attempts]
-            att_finish = att_finish[..., :n_attempts]
     attempts = (np.asarray(out["attempts"][idx][:n], np.int64)
                 if with_scenario else None)
+    att_start = att_finish = None
+    width = log_slots = None
+    if with_scenario and "att_log_start" in out:
+        width = (n_attempts if n_attempts is not None
+                 else int(attempts.max(initial=1)))
+        log_slots = int(out["att_log_start"].shape[-1])
+        if width > 1:
+            att_start, att_finish = attempt_records(
+                sl("start"), sl("finish"), attempts, sl("att_log_start"),
+                sl("att_log_finish"), width)
     ctrl_times = ctrl_caps = None
     if with_scenario and "ctrl_act" in out:
         from repro.core.des import unpack_ctrl_actions
@@ -369,8 +421,8 @@ def batch_trace(out: dict, idx: int, wl: M.Workload,
         else None,
         retries=int(np.maximum(attempts - 1, 0).sum())
         if attempts is not None else None,
-        attempt_slots=int(out["att_start"].shape[-1]) if "att_start" in out
-        else None,
+        attempt_slots=width,
+        attempt_log_slots=log_slots,
         rel_slot_s=rel_slot_s,
         **fl_cols,
     )

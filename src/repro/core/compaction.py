@@ -94,12 +94,12 @@ _DONE = 3         # vdes._DONE
 #: carry is per-replica scalar/buffer state and passes through untouched
 ROW_STATE_KEYS = ("phase", "task_idx", "t_next", "enq_wave", "attempt",
                   "start", "finish", "ready", "att_out",
-                  "att_start", "att_finish")
+                  "att_log_start", "att_log_finish", "rbase")
 #: ensemble input kwargs indexed by the pipeline-row axis (gather per row)
 ROW_INPUT_KEYS = ("arrival", "n_tasks", "task_res", "service", "priority",
                   "attempts", "attempt_service")
 #: static (non-array) ensemble kwargs passed through every segment
-STATIC_KEYS = ("n_attempt_slots", "admission_sort", "n_ctrl_slots",
+STATIC_KEYS = ("n_retry_slots", "admission_sort", "n_ctrl_slots",
                "n_probe_slots")
 _POSITIONAL = ("arrival", "n_tasks", "task_res", "service", "priority",
                "capacities")
@@ -135,7 +135,7 @@ class CompactionLog:
 @partial(jax.jit, static_argnames=("policy",) + STATIC_KEYS)
 def _segment_call(dev_inputs, full_state, rep_idx, row_idx, pool_base_w,
                   wave_budget, time_budget, *, policy,
-                  n_attempt_slots, admission_sort, n_ctrl_slots,
+                  n_retry_slots, admission_sort, n_ctrl_slots,
                   n_probe_slots):
     """One segment: gather the working set from the canonical full-size
     pytrees, run the wave loop under the wave/time budgets, scatter the
@@ -156,7 +156,7 @@ def _segment_call(dev_inputs, full_state, rep_idx, row_idx, pool_base_w,
     res = vdes.simulate_ensemble(
         *(w_inputs[k] for k in _POSITIONAL), policy,
         **{k: v for k, v in w_inputs.items() if k not in _POSITIONAL},
-        n_attempt_slots=n_attempt_slots, admission_sort=admission_sort,
+        n_retry_slots=n_retry_slots, admission_sort=admission_sort,
         n_ctrl_slots=n_ctrl_slots, n_probe_slots=n_probe_slots,
         resume=w_state, wave_budget=wave_budget, time_budget=time_budget,
         return_state=True)
@@ -339,9 +339,11 @@ def simulate_ensemble_compacted(
         res["ops_waves"] = st["ops_waves"]
     if "fleet_waves" in st:
         res["fleet_waves"] = st["fleet_waves"]
-    if statics["n_attempt_slots"] is not None:
-        res["att_start"] = st["att_start"]
-        res["att_finish"] = st["att_finish"]
+    if statics["n_retry_slots"] is not None:
+        # the retry log rides the carry where it has a column at all
+        for k in ("att_log_start", "att_log_finish"):
+            res[k] = st[k] if k in st else np.full(
+                (R0, N0, 0), np.nan, np.float32)
     if "controllers" in inputs and statics["n_ctrl_slots"]:
         res["ctrl_act"] = st["ctrl_act"]
         res["ctrl_n"] = st["ctrl_n"]

@@ -269,12 +269,17 @@ class SimTrace:
     # from buffers the row already holds; diagnostics like ops_waves, read
     # by no summary, record or parity check). retries: executed attempts
     # beyond each task's first (None without a scenario); attempt_slots:
-    # the per-attempt width A the batched call compiled with (None without
-    # per-attempt records); rel_slot_s: slot-seconds that the fired
-    # reliability events held out of service, from the first event to the
-    # horizon (None without reliability)
+    # the per-attempt width A of the row's records, att_start's last axis
+    # where it has one (None where the call recorded no attempts);
+    # attempt_log_slots: the retry-log width R the call compiled with, the
+    # most non-final attempts of any pipeline it ran (None where it
+    # recorded no attempts; also set by vdes.simulate_to_trace);
+    # rel_slot_s: slot-seconds that the fired reliability events held out
+    # of service, from the first event to the horizon (None without
+    # reliability)
     retries: Optional[int] = None
     attempt_slots: Optional[int] = None
+    attempt_log_slots: Optional[int] = None
     rel_slot_s: Optional[float] = None
 
     def action_timeline(self):

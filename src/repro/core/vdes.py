@@ -339,7 +339,7 @@ def admission_mask_select(res_q: jnp.ndarray, pkey: jnp.ndarray,
 
 
 @partial(jax.jit,
-         static_argnames=("policy", "n_attempt_slots", "admission_sort",
+         static_argnames=("policy", "n_retry_slots", "admission_sort",
                           "n_ctrl_slots", "n_probe_slots", "n_rel_slots",
                           "return_state", "batch_axis"))
 def simulate(vwl: VWorkload, capacities: jnp.ndarray, policy: int = POLICY_FIFO,
@@ -348,7 +348,7 @@ def simulate(vwl: VWorkload, capacities: jnp.ndarray, policy: int = POLICY_FIFO,
              backoff=None,
              attempt_service: Optional[jnp.ndarray] = None,
              policy_dyn: Optional[jnp.ndarray] = None,
-             n_attempt_slots: Optional[int] = None,
+             n_retry_slots: Optional[int] = None,
              controller: Optional[jnp.ndarray] = None,
              fail_holds_frac=None,
              admission_sort: str = "select",
@@ -372,10 +372,19 @@ def simulate(vwl: VWorkload, capacities: jnp.ndarray, policy: int = POLICY_FIFO,
     ``vwl.service``) — retry resampling stays pure: every draw happens
     outside the jitted function. ``policy_dyn`` is a *traced* i32 scalar that
     overrides the static ``policy`` so a vmapped batch can mix admission
-    policies across its replica axis in one compiled program. With
-    ``n_attempt_slots = A`` the engine also records per-attempt
-    ``att_start``/``att_finish [N, T, A]`` tensors (NaN where the attempt
-    never ran) for exact utilization/cost accounting under heavy retry.
+    policies across its replica axis in one compiled program.
+
+    ``n_retry_slots = R`` (static; None = no per-attempt record) records
+    every attempt's start and finish for exact utilization/cost accounting
+    under heavy retry. A task's last executed attempt is its ``start``/
+    ``finish`` column already; every earlier one goes to the pipeline's
+    retry log ``att_log_start``/``att_log_finish [N, R]`` (NaN where
+    unwritten), task after task: attempt ``k`` of task ``t`` at slot
+    ``sum(attempts[:t] - 1) + k``. ``R`` must cover the most non-final
+    attempts any pipeline requests (:func:`repro.core.batching.retry_width`);
+    with ``R = 0`` the loop carries no log and the two come back ``[N, 0]``.
+    :func:`repro.core.batching.attempt_records` places the log and the
+    final attempts into the ``[N, T, A]`` per-attempt arrays on the host.
 
     ``controller`` is a flat ``[C]`` ControllerParams tensor (see module
     docstring; ``C = CTRL_HEADER + CTRL_FIELDS * nres``) driving closed-loop
@@ -544,11 +553,15 @@ def simulate(vwl: VWorkload, capacities: jnp.ndarray, policy: int = POLICY_FIFO,
     )
     if count_ops:
         state["ops_waves"] = jnp.int32(0)
-    if n_attempt_slots is not None:
-        state["att_start"] = jnp.full((n, T, n_attempt_slots), jnp.nan,
-                                      jnp.float32)
-        state["att_finish"] = jnp.full((n, T, n_attempt_slots), jnp.nan,
-                                       jnp.float32)
+    # the retry log of non-final attempts, and each pipeline's offset in
+    # it of its current task (the retries its earlier tasks ran)
+    log_retries = n_retry_slots is not None and n_retry_slots > 0
+    if log_retries:
+        state["att_log_start"] = jnp.full((n, n_retry_slots), jnp.nan,
+                                          jnp.float32)
+        state["att_log_finish"] = jnp.full((n, n_retry_slots), jnp.nan,
+                                           jnp.float32)
+        state["rbase"] = jnp.zeros((n,), jnp.int32)
     rec_ctrl = has_ctrl and n_ctrl_slots is not None and n_ctrl_slots > 0
     if has_ctrl:
         state["ctrl_cap"] = c_base                       # continuous, f32
@@ -661,6 +674,11 @@ def simulate(vwl: VWorkload, capacities: jnp.ndarray, policy: int = POLICY_FIFO,
             delay = jnp.minimum(bo[0] * bo[1] ** att.astype(jnp.float32),
                                 bo[2])
         succeeding = finishing & ~retrying
+        if log_retries:
+            with jax.named_scope("retry"):
+                # a task that succeeds leaves ``att`` non-final attempts in
+                # the log: its successor's slots start after them
+                s["rbase"] = s["rbase"] + jnp.where(succeeding, att, 0)
 
         task_idx = task_idx + succeeding.astype(jnp.int32)
         att = jnp.where(retrying, att + 1,
@@ -843,10 +861,11 @@ def simulate(vwl: VWorkload, capacities: jnp.ndarray, policy: int = POLICY_FIFO,
 
         # a failing attempt (known at admission from the pre-sampled attempt
         # tensor) may hold its slot for only a fraction of the service time
+        if fail_holds_frac is not None or log_retries:
+            will_fail = (att + 1) < _take_cols(att_req, oh, 0)
         if fail_holds_frac is None:
             dur = svc
         else:
-            will_fail = (att + 1) < _take_cols(att_req, oh, 0)
             dur = jnp.where(will_fail,
                             jnp.asarray(fail_holds_frac, jnp.float32) * svc,
                             svc)
@@ -866,17 +885,20 @@ def simulate(vwl: VWorkload, capacities: jnp.ndarray, policy: int = POLICY_FIFO,
                            == jnp.arange(nres, dtype=jnp.int32)[None, :]),
                         axis=0, dtype=jnp.int32)
         s["free"] = s["free"] - taken
-        if n_attempt_slots is not None:
+        if log_retries:
             with jax.named_scope("attempts"):
-                # per-attempt records: the whole [N, T, A] buffers rewritten
-                ka = jnp.clip(att, 0, n_attempt_slots - 1)
-                adm_slot = adm_col[:, :, None] & (
-                    ka[:, None, None]
-                    == jnp.arange(n_attempt_slots,
-                                  dtype=jnp.int32)[None, None, :])
-                s["att_start"] = jnp.where(adm_slot, t_star, s["att_start"])
-                s["att_finish"] = jnp.where(adm_slot, t_fin[:, None, None],
-                                            s["att_finish"])
+                # an admitted attempt that will fail goes to its pipeline's
+                # retry log at slot rbase + att (the final one is start/
+                # finish itself): a dense one-hot row write over [N, R], as
+                # ctrl_act/rel_act (a traced-index scatter serializes
+                # under vmap)
+                log_w = (will_fail & admitted)[:, None] & (
+                    (s["rbase"] + att)[:, None]
+                    == jnp.arange(n_retry_slots, dtype=jnp.int32)[None, :])
+                s["att_log_start"] = jnp.where(log_w, t_star,
+                                               s["att_log_start"])
+                s["att_log_finish"] = jnp.where(log_w, t_fin[:, None],
+                                                s["att_log_finish"])
         return s
 
     def _fleet_stage(s, t_star):
@@ -1174,9 +1196,13 @@ def simulate(vwl: VWorkload, capacities: jnp.ndarray, policy: int = POLICY_FIFO,
         res["ops_waves"] = out["ops_waves"]
     if has_fleet:
         res["fleet_waves"] = out["fleet_waves"]
-    if n_attempt_slots is not None:
-        res["att_start"] = out["att_start"]
-        res["att_finish"] = out["att_finish"]
+    if log_retries:
+        res["att_log_start"] = out["att_log_start"]
+        res["att_log_finish"] = out["att_log_finish"]
+    elif n_retry_slots is not None:
+        # recorded, and no attempt to log: the final ones are start/finish
+        res["att_log_start"] = res["att_log_finish"] = jnp.full(
+            (n, 0), jnp.nan, jnp.float32)
     if rec_ctrl:
         res["ctrl_act"] = out["ctrl_act"]
         res["ctrl_n"] = out["ctrl_n"]
@@ -1217,7 +1243,7 @@ def simulate_to_trace(wl: M.Workload, platform: Optional[M.PlatformConfig] = Non
     trace's ``rel_times``/``rel_caps``). ``admission_sort`` selects the
     admission ranking as in :func:`simulate`."""
     platform = platform or M.PlatformConfig()
-    att_start = att_finish = None
+    att_start = att_finish = n_retry = None
     ctrl_times = ctrl_caps = None
     fl = fleet
     if fl is not None and float(np.asarray(fl.trig)[TRIG_INTERVAL]) <= 0.0:
@@ -1253,10 +1279,11 @@ def simulate_to_trace(wl: M.Workload, platform: Optional[M.PlatformConfig] = Non
         att_svc = getattr(scenario, "attempt_service", None)
         ctrl = getattr(scenario, "controller", None)
         frac = float(getattr(scenario, "fail_holds_frac", 1.0))
-        from repro.core.batching import attempt_width
+        from repro.core.batching import (attempt_records, attempt_width,
+                                         retry_width)
         slots = attempt_width(scenario)
-        if slots == 1:   # no retries: single-attempt records already exact
-            slots = None
+        if slots > 1:    # else single-attempt records already are exact
+            n_retry = retry_width(scenario.attempts)
         n_ctrl = ctrl_tick_bound(ctrl) if ctrl is not None else 0
         res = simulate(vwl, jnp.asarray(platform.capacities, jnp.int32), policy,
                        cap_times=jnp.asarray(scenario.cap_times, jnp.float32),
@@ -1264,7 +1291,7 @@ def simulate_to_trace(wl: M.Workload, platform: Optional[M.PlatformConfig] = Non
                        backoff=jnp.asarray(scenario.backoff, jnp.float32),
                        attempt_service=None if att_svc is None
                        else jnp.asarray(att_svc, jnp.float32),
-                       n_attempt_slots=slots,
+                       n_retry_slots=n_retry,
                        controller=None if ctrl is None
                        else jnp.asarray(ctrl, jnp.float32),
                        fail_holds_frac=None if frac >= 1.0 else frac,
@@ -1273,9 +1300,10 @@ def simulate_to_trace(wl: M.Workload, platform: Optional[M.PlatformConfig] = Non
         caps0 = np.asarray(scenario.cap_vals[0], np.int64)
         attempts = np.asarray(res["attempts"], np.int64)
         completed = np.asarray(res["done"])
-        if slots is not None:
-            att_start = np.asarray(res["att_start"], np.float64)
-            att_finish = np.asarray(res["att_finish"], np.float64)
+        if n_retry is not None:
+            att_start, att_finish = attempt_records(
+                res["start"], res["finish"], attempts, res["att_log_start"],
+                res["att_log_finish"], slots)
         if ctrl is not None and \
                 float(np.asarray(ctrl)[CTRL_INTERVAL]) > 0.0:
             # enabled controller: realized timeline present (maybe empty),
@@ -1325,6 +1353,7 @@ def simulate_to_trace(wl: M.Workload, platform: Optional[M.PlatformConfig] = Non
         ctrl_caps=ctrl_caps,
         waves=int(res["waves"]),
         fleet_waves=int(res["fleet_waves"]) if fl is not None else None,
+        attempt_log_slots=n_retry,
         **fl_cols,
     )
 
@@ -1337,14 +1366,14 @@ _ROWS = "rows"   # the name of the ensemble's vmap axis
 
 
 @partial(jax.jit,
-         static_argnames=("policy", "n_attempt_slots", "admission_sort",
+         static_argnames=("policy", "n_retry_slots", "admission_sort",
                           "n_ctrl_slots", "n_probe_slots", "n_rel_slots",
                           "return_state"))
 def simulate_ensemble(arrival, n_tasks, task_res, service, priority,
                       capacities, policy: int = POLICY_FIFO,
                       attempts=None, cap_times=None, cap_vals=None,
                       backoff=None, policies=None, attempt_service=None,
-                      n_attempt_slots: Optional[int] = None,
+                      n_retry_slots: Optional[int] = None,
                       controllers=None, fail_holds_frac=None,
                       admission_sort: str = "select",
                       n_ctrl_slots: Optional[int] = None,
@@ -1367,8 +1396,10 @@ def simulate_ensemble(arrival, n_tasks, task_res, service, priority,
     the replica axis. ``policies [R]`` (i32) assigns a (possibly different)
     admission policy per replica via the traced ``policy_dyn`` path, so a
     whole experiment grid — capacities, scenarios, controller gains, *and*
-    schedulers — lowers to this one jit+vmap call. ``n_attempt_slots``
-    (static) turns on per-attempt start/finish recording;
+    schedulers — lowers to this one jit+vmap call. ``n_retry_slots``
+    (static) turns on per-attempt start/finish recording: the retry logs
+    come back ``att_log_start``/``att_log_finish [R, N, n_retry_slots]``
+    (see :func:`simulate`);
     ``admission_sort`` (static) selects the ranking as in :func:`simulate`;
     ``n_ctrl_slots`` (static; the max :func:`repro.core.des.ctrl_tick_bound`
     over the batch) turns on realized-capacity-timeline recording — the
@@ -1458,7 +1489,7 @@ def simulate_ensemble(arrival, n_tasks, task_res, service, priority,
                         backoff=m["backoff"],
                         attempt_service=m.get("attempt_service"),
                         policy_dyn=m.get("policy_dyn"),
-                        n_attempt_slots=n_attempt_slots,
+                        n_retry_slots=n_retry_slots,
                         controller=m.get("controllers"),
                         fail_holds_frac=m.get("fail_holds_frac"),
                         admission_sort=admission_sort,

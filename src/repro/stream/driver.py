@@ -57,8 +57,8 @@ import jax
 
 from repro.core import model as M
 from repro.core import trace, vdes
-from repro.core.batching import (batch_trace, stack_fleets, stack_probes,
-                                 stack_scenarios)
+from repro.core.batching import (attempt_records, batch_trace,
+                                 stack_fleets, stack_probes, stack_scenarios)
 from repro.core.compaction import ROW_STATE_KEYS, _bucket
 from repro.core.des import (CTRL_INF, POLICY_FIFO, ctrl_tick_bound,
                             unpack_ctrl_actions, unpack_fleet_actions)
@@ -106,6 +106,9 @@ class StreamResult:
     fleet_cols: Optional[Dict] = None
     probe_times: Optional[np.ndarray] = None
     probe_vals: Optional[np.ndarray] = None
+    # the retry-log width every window compiled with (None: no per-attempt
+    # record), as SimTrace.attempt_log_slots
+    attempt_log_slots: Optional[int] = None
 
 
 class _StreamPlan:
@@ -165,7 +168,6 @@ class _StreamPlan:
                               asv.shape[2] if asv is not None else 1)
             self.has_asv = asv is not None
             self._replay_off = 0
-        self.n_attempt_slots = self.a_stat if self.a_stat > 1 else None
         self.n_ctrl_slots = (ctrl_tick_bound(self.controller) or None
                              if self.controller is not None else None)
 
@@ -277,12 +279,17 @@ class _StreamPlan:
         kw = stack_scenarios([comp], n_max, self.horizon_s,
                              services=[services], record_attempts=True,
                              record_ctrl=True)
-        kw.pop("n_attempt_slots", None)
+        kw.pop("n_retry_slots", None)
         kw.pop("n_ctrl_slots", None)
         return kw
 
-    def statics(self) -> Dict:
-        return dict(n_attempt_slots=self.n_attempt_slots,
+    def statics(self, T: int) -> Dict:
+        """The static engine arguments of every window of ``T``-task rows.
+        The attempts are drawn block by block, so the retry log takes the
+        most non-final attempts any pipeline can request: ``a_stat - 1``
+        per task."""
+        return dict(n_retry_slots=(self.a_stat - 1) * T if self.a_stat > 1
+                    else None,
                     admission_sort=self.admission_sort,
                     n_ctrl_slots=self.n_ctrl_slots,
                     n_probe_slots=self.n_probe_slots)
@@ -329,24 +336,30 @@ def _empty_buf(T: int, A: int, has_asv: bool) -> Dict[str, np.ndarray]:
 
 def _extract_records(content: Dict, st: Dict, row_idx: np.ndarray,
                      gids: np.ndarray, caps: np.ndarray,
-                     arrival: Optional[np.ndarray] = None
-                     ) -> trace.TaskRecords:
+                     arrival: Optional[np.ndarray] = None,
+                     width: int = 1) -> trace.TaskRecords:
     """Records for the given working-set rows, straight through the ONE
     flattener every engine uses — pipeline ids remapped to global ids.
     ``arrival`` overrides the content arrivals (retraining-pool activation
     times; NaN rows are latent and drop out exactly like the one-shot
-    path's)."""
+    path's); ``width`` is the per-attempt records' width (the plan's
+    ``a_stat``), rebuilt from the carry's retry log where it holds one."""
     sl = lambda k: np.asarray(st[k][0][row_idx], np.float64)
+    attempts = np.asarray(st["att_out"][0][row_idx], np.int64)
+    att_start = att_finish = None
+    if width > 1 and "att_log_start" in st:
+        att_start, att_finish = attempt_records(
+            sl("start"), sl("finish"), attempts, sl("att_log_start"),
+            sl("att_log_finish"), width)
     tr = M.SimTrace(
         start=sl("start"), finish=sl("finish"), ready=sl("ready"),
         n_tasks=content["n_tasks"].astype(np.int64),
         task_res=content["task_res"], task_type=content["task_type"],
         arrival=(arrival if arrival is not None else content["arrival"]),
         capacities=caps,
-        attempts=np.asarray(st["att_out"][0][row_idx], np.int64),
+        attempts=attempts,
         completed=np.asarray(st["phase"][0][row_idx] == _DONE),
-        att_start=sl("att_start") if "att_start" in st else None,
-        att_finish=sl("att_finish") if "att_finish" in st else None)
+        att_start=att_start, att_finish=att_finish)
     wl_view = SimpleNamespace(read_bytes=content["read_bytes"],
                               write_bytes=content["write_bytes"],
                               framework=content["framework"])
@@ -376,7 +389,8 @@ def _fresh_rows(key: str, proto: np.ndarray, n: int, arr32: np.ndarray,
         return np.full(shape, _DONE, proto.dtype)
     if key == "t_next":
         return arr32[None, :].astype(proto.dtype)
-    if key in ("start", "finish", "ready", "att_start", "att_finish"):
+    if key in ("start", "finish", "ready", "att_log_start",
+               "att_log_finish"):
         return np.full(shape, np.nan, proto.dtype)
     return np.zeros(shape, proto.dtype)     # phases, indices, counters
 
@@ -446,7 +460,6 @@ def stream_simulate(
     probe_kw = stack_probes([plan.probe], [cf]) if plan.probe else {}
     probe_kw.pop("n_probe_slots", None)
     fleet_kw = stack_fleets([cf], n_max=0) if cf is not None else {}
-    statics = plan.statics()
     caps = plan.caps
 
     # content template dims from the first rows seen
@@ -455,6 +468,7 @@ def stream_simulate(
          else (pool["task_type"].shape[1] if pool is not None else MAX_TASKS))
     if not first and pool is None and wm.exhausted:
         raise ValueError(f"source {source.name!r} yielded no rows")
+    statics = plan.statics(T)
     buf = _merge(_empty_buf(T, plan.a_stat, plan.has_asv), first)
 
     recs: List[trace.TaskRecords] = []
@@ -580,20 +594,22 @@ def stream_simulate(
             final_gids = buf["gid"]
             if n_exo:
                 emit(_extract_records(buf, st, final_exo_rows, final_gids,
-                                      plan.caps))
+                                      plan.caps, width=plan.a_stat))
             if P:
                 # pool pipeline ids follow ALL exogenous ids, exactly like
                 # the one-shot extended workload's layout
                 pool_gids = int(wm.n_rows) + np.arange(P)
                 emit(_extract_records(
                     pool, st, n_exo + np.arange(P), pool_gids, plan.caps,
-                    arrival=np.asarray(st["pool_arr"][0], np.float64)))
+                    arrival=np.asarray(st["pool_arr"][0], np.float64),
+                    width=plan.a_stat))
             break
 
         retired = np.flatnonzero(exo_done)
         if retired.size:
             emit(_extract_records(_take(buf, retired), st, retired,
-                                 buf["gid"][retired], plan.caps))
+                                 buf["gid"][retired], plan.caps,
+                                 width=plan.a_stat))
         keep_idx = np.flatnonzero(~exo_done)
         prev_pool_off = n_exo
         buf = _take(buf, keep_idx)
@@ -639,7 +655,7 @@ def stream_simulate(
         peak_rows=W, peak_live=peak_live + P, wall_s=wall,
         ingest_s=ingest_s[0], ctrl_times=ctrl_times, ctrl_caps=ctrl_caps,
         fleet_cols=fleet_cols, probe_times=probe_times,
-        probe_vals=probe_vals)
+        probe_vals=probe_vals, attempt_log_slots=statics["n_retry_slots"])
 
 
 # ---------------------------------------------------------------------------
@@ -726,10 +742,10 @@ def oneshot_reference(
     out = vdes.simulate_ensemble(
         *(inputs[k_] for k_ in _POSITIONAL), plan.policy,
         **{k_: v for k_, v in inputs.items() if k_ not in _POSITIONAL},
-        **plan.statics())
+        **plan.statics(wl_ext.max_tasks))
     out = {k_: np.asarray(v) for k_, v in out.items()}
     tr = batch_trace(out, 0, wl_ext, plan.caps, with_scenario=True,
-                     fleet=cf, probe=plan.probe)
+                     fleet=cf, probe=plan.probe, n_attempts=plan.a_stat)
     rec = trace.flatten_trace(tr, wl_ext)
     fleet_cols = None
     if cf is not None:

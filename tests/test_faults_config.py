@@ -157,32 +157,35 @@ def test_retries_and_rack_outages_fire(day_results, row):
     # some outage took slots down
     assert np.asarray(tr.rel_caps).min() < 0
     assert tr.rel_slot_s > 0
-    assert tr.attempt_slots == max(
-        t.att_start.shape[2] for t in (r.trace for r in day_results[0]))
+    # the row's own per-attempt width, and a retry log wide enough for
+    # the busiest pipeline of the batch
+    assert tr.attempt_slots == tr.att_start.shape[2]
+    assert tr.attempt_log_slots == max(
+        int(np.maximum(r.trace.attempts - 1, 0).sum(axis=1).max())
+        for r in day_results[0])
 
 
 # ---------------------------------------------------- the counters
 
 def _hand_out():
     """A one-row ``simulate_ensemble`` output by hand: 2 pipelines of 2
-    tasks, a batch width of 3 attempt slots; pipeline 0's first task ran
-    twice, pipeline 1 never ran its second task. Reliability: 2 slots down
-    at t=10, 1 back at t=40, the rest back at t=70; horizon 100."""
+    tasks, a retry log of one column; pipeline 0's first task ran twice
+    ([0, 5] failed, [20, 25] succeeded), pipeline 1 never ran its second
+    task. Reliability: 2 slots down at t=10, 1 back at t=40, the rest back
+    at t=70; horizon 100."""
     nan = np.nan
-    att_start = np.full((1, 3, 2, 3), nan)     # one padding pipeline
-    att_finish = np.full((1, 3, 2, 3), nan)
-    att_start[0, 0, 0, :2], att_finish[0, 0, 0, :2] = [0, 20], [5, 25]
-    att_start[0, 0, 1, 0], att_finish[0, 0, 1, 0] = 25, 30
-    att_start[0, 1, 0, 0], att_finish[0, 1, 0, 0] = 1, 2
+    log_start = np.full((1, 3, 1), nan)        # one padding pipeline
+    log_finish = np.full((1, 3, 1), nan)
+    log_start[0, 0, 0], log_finish[0, 0, 0] = 0, 5
     rel_act = np.full((1, 4, 3), nan, np.float32)
     rel_act[0, :3] = [[10, -2, 0], [40, -1, 0], [70, 0, 0]]
     return dict(
-        start=np.array([[[0, 25], [1, nan], [nan, nan]]]),
+        start=np.array([[[20, 25], [1, nan], [nan, nan]]]),
         finish=np.array([[[25, 30], [2, nan], [nan, nan]]]),
         ready=np.zeros((1, 3, 2)), waves=np.array([9]),
         attempts=np.array([[[2, 1], [1, 0], [0, 0]]], np.int32),
         done=np.array([[True, False, False]]),
-        att_start=att_start, att_finish=att_finish,
+        att_log_start=log_start, att_log_finish=log_finish,
         rel_act=rel_act, rel_n=np.array([3]))
 
 
@@ -208,27 +211,32 @@ def test_counters_of_a_hand_built_row():
     tr = batching.batch_trace(_hand_out(), 0, _hand_wl(), np.array([2, 1]),
                               reliability=_Rel(), n_attempts=2)
     assert tr.retries == 1                 # pipeline 0's first task
-    assert tr.attempt_slots == 3           # the batch's width
+    assert tr.attempt_slots == 2           # the row's own width
+    assert tr.attempt_log_slots == 1       # the call's retry log
     # 2 slots x 30 s + 1 slot x 30 s, then nothing down until 100
     assert tr.rel_slot_s == 90.0
     # the row's own two attempt columns, as a one-row run records them
     assert tr.att_start.shape == (2, 2, 2)
     np.testing.assert_array_equal(tr.att_start[0, 0], [0, 20])
+    np.testing.assert_array_equal(tr.att_finish[0, 0], [5, 25])
+    np.testing.assert_array_equal(tr.att_start[0, 1], [25, np.nan])
+    np.testing.assert_array_equal(tr.att_finish[1],
+                                  [[2, np.nan], [np.nan, np.nan]])
     np.testing.assert_array_equal(tr.rel_times, [10, 40, 70])
 
 
 def test_counters_where_the_row_has_none():
     out = _hand_out()
-    for k in ("att_start", "att_finish", "rel_act", "rel_n"):
+    for k in ("att_log_start", "att_log_finish", "rel_act", "rel_n"):
         del out[k]
     tr = batching.batch_trace(out, 0, _hand_wl(), np.array([2, 1]),
                               with_scenario=False)
-    assert (tr.retries, tr.attempt_slots, tr.rel_slot_s) == (None, None,
-                                                             None)
+    assert (tr.retries, tr.attempt_slots, tr.attempt_log_slots,
+            tr.rel_slot_s) == (None, None, None, None)
     # a row that retries nothing keeps no per-attempt buffers
     tr = batching.batch_trace(_hand_out(), 0, _hand_wl(), np.array([2, 1]),
                               n_attempts=1)
-    assert tr.att_start is None and tr.attempt_slots == 3
+    assert tr.att_start is None and tr.attempt_slots == 1
 
 
 def test_down_node_seconds_holds_the_last_event_to_the_horizon():
@@ -241,7 +249,7 @@ def test_down_node_seconds_holds_the_last_event_to_the_horizon():
 
 # ------------------------------------------------------ the scopes
 
-_STATIC = ("policy", "n_attempt_slots", "admission_sort", "n_ctrl_slots",
+_STATIC = ("policy", "n_retry_slots", "admission_sort", "n_ctrl_slots",
            "n_probe_slots", "n_rel_slots", "return_state", "batch_axis")
 
 
@@ -264,7 +272,7 @@ def test_nested_scopes_change_no_operation():
         jnp.asarray(np.tile([[1, 3]], (n, 1)), jnp.int32))
     args = (vwl, jnp.asarray([2], jnp.int32))
     kwargs = dict(backoff=jnp.asarray([30.0, 2.0, 1800.0]),
-                  n_attempt_slots=3,
+                  n_retry_slots=2,
                   rel_times=jnp.asarray([2.0, 9.0]),
                   rel_deltas=jnp.asarray([[-1], [1]], jnp.int32),
                   n_rel_slots=2)

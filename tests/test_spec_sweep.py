@@ -295,9 +295,9 @@ def test_legacy_stack_wrapper_keeps_recording_off(rng):
     comps = [_fail_scenario().compile(w, platform(), 300.0, seed=i)
              for i, w in enumerate(wls)]
     legacy = stack_compiled_scenarios(comps, 20, 300.0)
-    assert "n_attempt_slots" not in legacy
+    assert "n_retry_slots" not in legacy
     exact = stack_scenarios(comps, 20, 300.0)
-    assert exact["n_attempt_slots"] > 1
+    assert exact["n_retry_slots"] > 0
 
 
 def test_resample_engine_parity_integer_times(rng):
@@ -439,7 +439,8 @@ def test_pad_workloads_and_stack_scenarios_shapes(rng):
     kw = stack_scenarios(comps, 45, 300.0)
     assert kw["attempts"].shape == (2, 45, wls[0].max_tasks)
     assert kw["cap_times"].shape[0] == 2
-    assert kw["n_attempt_slots"] >= int(kw["attempts"].max())
+    # the retry log holds every non-final attempt of the busiest pipeline
+    assert kw["n_retry_slots"] == int((kw["attempts"] - 1).sum(axis=2).max())
     # padded rows are inert single-attempt tasks
     assert (kw["attempts"][0, 30:] == 1).all()
 
